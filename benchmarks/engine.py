@@ -340,7 +340,10 @@ def main(argv=None) -> int:
                     help="machine-readable throughput report path")
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
     from repro.models.cnn import MLPClassifier
+
+    compile_cache()
 
     model = MLPClassifier(feature_dim=32, num_classes=10, hidden=(64, 64))
 

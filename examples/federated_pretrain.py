@@ -27,6 +27,7 @@ import numpy as np
 from repro.configs.base import ATTN_GLOBAL, ArchConfig
 from repro.data import make_federated_lm
 from repro.fl import FLrce, run_federated
+from repro.launch import compile_cache
 from repro.models import LMClassifier
 from repro.models.cnn import param_count
 
@@ -62,6 +63,7 @@ def main() -> None:
     ap.add_argument("--psi", type=float, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache()
 
     cfg = make_cfg(args.size)
     model = LMClassifier(cfg, seq_len=args.seq)

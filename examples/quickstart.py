@@ -9,7 +9,10 @@ import jax
 
 from repro.data import make_federated_classification
 from repro.fl import FLrce, run_federated
+from repro.launch import compile_cache
 from repro.models.cnn import MLPClassifier, param_count
+
+compile_cache()
 
 M, P, T, EPOCHS = 20, 5, 25, 2
 

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch, list_archs
+from repro.launch import compile_cache
 from repro.launch.serve import generate
 from repro.models import TransformerLM
 
@@ -25,6 +26,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=12)
     ap.add_argument("--gen", type=int, default=24)
     args = ap.parse_args()
+    compile_cache()
 
     cfg = get_arch(args.arch, reduced=True)
     model = TransformerLM(cfg, remat=False)

@@ -5,9 +5,12 @@ PR 5's layout pinning exists so the chunk program compiles exactly once
 per (strategy, mesh, knobs) job — a silent recompile (layout flip, shape
 drift in the candidate remap, a host int leaking into the carry) costs
 more than the chunk it dispatches.  ``jax.monitoring`` emits exactly one
-``/jax/core/compile/backend_compile_duration`` event per real XLA
-compilation and none on a cache hit, which makes "no silent recompiles"
-an assertable number::
+``/jax/core/compile/backend_compile_duration`` event per program that
+reaches the backend — compiled by XLA or loaded from the persistent
+compilation cache (the event times the whole ``compile_or_get_cached``
+call) — and none on an in-memory cache hit.  So "no silent recompiles" is
+an assertable number, and it reads the same on a cold and a warm
+persistent cache::
 
     with CompileCounter() as cc:
         run_federated(..., driver="scan")
@@ -51,7 +54,8 @@ def _ensure_registered() -> None:
 
 
 class CompileCounter:
-    """Context manager counting XLA backend compiles inside its block."""
+    """Context manager counting XLA backend compiles (or persistent-cache
+    loads) inside its block."""
 
     def __init__(self) -> None:
         self._count = 0

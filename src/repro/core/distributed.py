@@ -29,19 +29,11 @@ _EPS = 1e-12
 
 
 def _shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """jax.shard_map with replication checks off, across jax versions.
-
-    jax >= 0.5 exposes ``jax.shard_map(..., check_vma=...)``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.  Checks are
-    disabled either way because pallas_call outputs carry no vma metadata.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with the vma check off: pallas_call outputs carry no
+    varying-manual-axes metadata."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 # ---------------------------------------------------------------------------

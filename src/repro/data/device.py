@@ -85,7 +85,7 @@ def flat_row_index(cids: np.ndarray, pos: np.ndarray, n_max: int) -> np.ndarray:
 class DeviceClientStore:
     """Every client's shard stacked into device tensors, padded to N_max."""
 
-    x: jax.Array              # (M[_pad], N_max, *feat) float32
+    x: jax.Array              # (M[_pad], N_max, F) float32
     y: jax.Array              # (M[_pad], N_max) int32
     sizes: jax.Array          # (M,) int32 — real samples per client
     sizes_host: np.ndarray    # int64 host copy for schedule building / the ledger
@@ -147,7 +147,7 @@ class DeviceClientStore:
         full-universe store with full-universe schedules; with per-candidate
         schedules a resident store passes global client ids as ``rows`` and
         candidate-relative slots as ``ids`` (a paged store's rows ARE slots,
-        so the default applies again).  Returns ``(x (P,S,B,*feat),
+        so the default applies again).  Returns ``(x (P,S,B,F),
         y (P,S,B), sample_w (P,S,B), step_valid (P,S))`` — exactly a
         :class:`CohortPlan`'s arrays.
         """
@@ -193,7 +193,7 @@ class HostClientStore:
     2 at most two pages are live at once.
     """
 
-    x: np.ndarray             # (M, N_max, *feat) float32
+    x: np.ndarray             # (M, N_max, F) float32, features flattened
     y: np.ndarray             # (M, N_max) int32
     sizes_host: np.ndarray    # (M,) int64 — real samples per client
 
@@ -213,13 +213,19 @@ class HostClientStore:
         sample rows land via a single int64 flat-index assignment
         (:func:`flat_row_index`), so construction is O(total samples) NumPy
         work even at M ≥ 10⁵ clients.
+
+        Each sample's features are stored flattened to one trailing axis of
+        F values (models reshape what they need, e.g. ``PaperCNN``): a TPU
+        tiles the two minor dims of an array by (8, 128), so an image
+        store's (W, C) = (32, 3) minor dims would take 4x their bytes in
+        HBM, and so would every batch gathered from it.
         """
         sizes = ds.client_sizes().astype(np.int64)
         m = len(ds.client_indices)
         n_max = max(1, int(sizes.max()) if m else 1)
         validate_store_geometry(m, n_max)
-        feat = ds.x.shape[1:]
-        x = np.zeros((m, n_max, *feat), np.float32)
+        feat = int(np.prod(ds.x.shape[1:]))
+        x = np.zeros((m, n_max, feat), np.float32)
         y = np.zeros((m, n_max), np.int32)
         if m and sizes.sum():
             cat = np.concatenate(
@@ -229,7 +235,7 @@ class HostClientStore:
             starts = np.cumsum(sizes) - sizes
             pos = np.arange(int(sizes.sum()), dtype=np.int64) - np.repeat(starts, sizes)
             flat = flat_row_index(rows, pos, n_max)
-            x.reshape(m * n_max, *feat)[flat] = ds.x[cat]
+            x.reshape(m * n_max, feat)[flat] = ds.x[cat].reshape(len(cat), feat)
             y.reshape(m * n_max)[flat] = ds.y[cat]
         return cls(x=x, y=y, sizes_host=sizes)
 

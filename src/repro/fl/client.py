@@ -323,7 +323,16 @@ class BatchedCohortTrainer:
             donate_argnums=(4,),
         )
 
-    def _make_train(self):
+    def _make_train(self, load=None):
+        """The cohort program: vmap over clients of a ``lax.scan`` over steps.
+
+        ``load`` maps one step's scanned ``(xs, ys)`` entries to that step's
+        ``(x, y)`` batch; ``None`` scans materialized batches.  The compiled
+        chunks scan sample positions and client rows and gather each step's
+        batch from the device store, so a round never holds its whole
+        (P, S, B, F) batch tensor (1.9 GB at the paper's CIFAR width, 4x
+        that in the TPU's tiled layout).
+        """
         model, lr = self.model, self.lr
 
         def per_example_losses(p, x, y):
@@ -334,6 +343,8 @@ class BatchedCohortTrainer:
         def one_client(global_params, xs, ys, ws, valid, mask, freeze, prox_mu, *, use_prox, has_mask):
             def step(params, inp):
                 x, y, w, v = inp
+                if load is not None:
+                    x, y = load(x, y)
 
                 def loss_fn(p):
                     q = jax.tree_util.tree_map(lambda a, m: a * m, p, mask) if has_mask else p
@@ -461,9 +472,11 @@ class ShardedCohortTrainer(BatchedCohortTrainer):
         self.reshard_cache_hits = 0
         self.reshard_cache_misses = 0
 
-    def _sharded_train_raw(self, use_prox: bool, has_mask: bool):
+    def _sharded_train_raw(self, use_prox: bool, has_mask: bool, load=None):
         """The bare mesh cohort program (not jitted) — the form the compiled
-        round chunks trace straight into their scan body.
+        round chunks trace straight into their scan body (with ``load``, see
+        :meth:`_make_train`: it closes over the chunk's traced store, so
+        that program is built per trace and not cached).
 
         Replicated-model path: shard_map the cohort program over ``data``
         (params replicated per shard).  Model-sharded path (the model
@@ -473,23 +486,24 @@ class ShardedCohortTrainer(BatchedCohortTrainer):
         never materialized replicated on any device.
         """
         key = (use_prox, has_mask)
-        if key not in self._sharded_raw_cache:
-            train = functools.partial(
-                self._make_train(), use_prox=use_prox, has_mask=has_mask
-            )
-            if self.param_shardings is not None:
-                self._sharded_raw_cache[key] = self._gspmd_train(train)
-            else:
-                from jax.sharding import PartitionSpec as P
-                from repro.core.distributed import _shard_map
+        if load is None and key in self._sharded_raw_cache:
+            return self._sharded_raw_cache[key]
+        train = functools.partial(
+            self._make_train(load), use_prox=use_prox, has_mask=has_mask
+        )
+        if self.param_shardings is not None:
+            program = self._gspmd_train(train)
+        else:
+            from jax.sharding import PartitionSpec as P
+            from repro.core.distributed import _shard_map
 
-                dspec = P(self.data_axis)
-                in_specs = (P(), dspec, dspec, dspec, dspec, dspec, dspec, dspec)
-                out_specs = (dspec, P(self.data_axis, None), dspec)
-                self._sharded_raw_cache[key] = _shard_map(
-                    train, self.mesh, in_specs, out_specs
-                )
-        return self._sharded_raw_cache[key]
+            dspec = P(self.data_axis)
+            in_specs = (P(), dspec, dspec, dspec, dspec, dspec, dspec, dspec)
+            out_specs = (dspec, P(self.data_axis, None), dspec)
+            program = _shard_map(train, self.mesh, in_specs, out_specs)
+        if load is None:
+            self._sharded_raw_cache[key] = program
+        return program
 
     def _gspmd_train(self, train):
         """GSPMD-partitioned cohort training for a model-sharded model.

@@ -148,16 +148,18 @@ def _bucket_candidates(n: int, cap: int) -> int:
 
 
 def _live_device_bytes() -> int:
-    """Total bytes of live device arrays (the driver's memory probe).
+    """Bytes in use on the local devices (the driver's memory probe).
 
-    Coarse by design: counts every live buffer in the process, which is
-    exactly what the flat-in-M acceptance check needs — if the paged path
-    leaked O(M) device state, it would show here.
+    Reads the allocator's ``bytes_in_use`` where the backend reports
+    ``memory_stats()`` (TPU/GPU); the CPU backend reports none, so there it
+    sums every live array.  Coarse by design: counts everything in the
+    process, which is exactly what the flat-in-M acceptance check needs — if
+    the paged path leaked O(M) device state, it would show here.
     """
-    try:
-        return sum(int(getattr(a, "nbytes", 0)) for a in jax.live_arrays())
-    except Exception:
-        return 0
+    stats = [d.memory_stats() for d in jax.local_devices()]
+    if all(s is not None for s in stats):
+        return sum(int(s["bytes_in_use"]) for s in stats)
+    return sum(int(a.nbytes) for a in jax.live_arrays())
 
 
 class _ChunkRunner:
@@ -179,10 +181,11 @@ class _ChunkRunner:
         # staleness-aware rounds: None ⇒ synchronous chunks (the arrival
         # buffer carry slot is an empty pytree and the body is untouched)
         self.async_plan = async_plan
-        # resident mode closes the chunk over the full device store; paged
-        # mode (store=None) receives each chunk's (P_cand, N_max, …) page as
-        # ordinary program inputs instead
-        self.store = store
+        # the chunk program takes its samples as ordinary (never donated)
+        # inputs: paged mode (store=None) each chunk's (P_cand, N_max, …)
+        # page, resident mode the full device store on every call.  Closed
+        # over, the store and eval set would be baked into the program as
+        # constants (GBs of HLO at the paper's CIFAR width).
         self.paged = paged
         self.unflatten = unflatten
         self.program = program
@@ -190,11 +193,9 @@ class _ChunkRunner:
         self.p = clients_per_round
         self.eval_every = eval_every
         self.max_rounds = max_rounds
-        self.eval_x, self.eval_y = eval_x, eval_y
         self.mesh = mesh
         if mesh is None:
             self._trainer = BatchedCohortTrainer(model, learning_rate, batch_size)
-            self._train_raw = self._trainer._make_train()
             self.p_pad = clients_per_round
         else:
             self._trainer = ShardedCohortTrainer(model, learning_rate, batch_size, mesh)
@@ -202,26 +203,31 @@ class _ChunkRunner:
             self.n_data = mesh.shape[self._trainer.data_axis]
             self.p_pad = pad_dim(clients_per_round, self.n_data)
         self._cache: Dict[Tuple[bool, bool], Any] = {}
-        # computed here (setup, outside the dispatch loop) so the recompile
+        # placed here (setup, outside the dispatch loop) so the recompile
         # sentinel's per-dispatch delta sees only the chunk program itself,
-        # not this one-off convert on a cold jit cache
-        self._sizes_f = None if paged else store.sizes.astype(jnp.float32)
-
-    def _build(self, use_prox: bool, has_mask: bool, carry_shardings=None):
-        store, program, unflatten = self.store, self.program, self.unflatten
-        p, transform, mesh = self.p, self.transform, self.mesh
-        paged, async_plan = self.paged, self.async_plan
-        eval_every, max_rounds = self.eval_every, self.max_rounds
-        eval_x, eval_y, model = self.eval_x, self.eval_y, self.model
-        sizes_f = self._sizes_f
-        eval_params = self.unflatten
+        # and committed (replicated on a mesh) like every other chunk input:
+        # an uncommitted single-device input is resharded at every dispatch
         if mesh is None:
-            train = self._train_raw
+            put = jax.device_put
         else:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            trainer = self._trainer
-            train_sharded = trainer._sharded_train_raw(use_prox, has_mask)
+            put = lambda a: jax.device_put(a, NamedSharding(mesh, P()))
+        self.eval_x, self.eval_y = put(eval_x), put(eval_y)
+        self._resident = None if paged else (
+            store.x, store.y, put(store.sizes.astype(jnp.float32))
+        )
+
+    def _build(self, use_prox: bool, has_mask: bool, carry_shardings=None):
+        program, unflatten = self.program, self.unflatten
+        p, transform, mesh = self.p, self.transform, self.mesh
+        paged, async_plan = self.paged, self.async_plan
+        eval_every, max_rounds = self.eval_every, self.max_rounds
+        model, trainer = self.model, self._trainer
+        eval_params = self.unflatten
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
             axes, p_pad = self.axes, self.p_pad
             rep_sharding = NamedSharding(mesh, P())
             # model-axis composition: the eval-time params of a model-sharded
@@ -235,7 +241,7 @@ class _ChunkRunner:
                         unflatten(wv), param_shardings,
                     )
 
-        def body_with(cand, page_x, page_y, page_sizes):
+        def body_with(cand, page_x, page_y, page_sizes, eval_x, eval_y):
             """The scan body, closed over this chunk's candidate remap.
 
             ``cand`` is the chunk's (P_cand,) sorted global candidate ids;
@@ -243,8 +249,18 @@ class _ChunkRunner:
             (schedules and pages are slot-indexed), and ``ids = cand[slots]``
             recovers global ids for sizes, the update transform and the
             strategy carry.  Paged mode gathers samples from the page by
-            slot; resident mode gathers from the full store by global id.
+            slot; resident mode's "page" is the full store, gathered by
+            global id.  The trainer gathers one step's batch at a time
+            (``load``): it scans sample positions and client rows.
             """
+
+            def load(pos, rows):
+                return page_x[rows, pos], page_y[rows, pos]
+
+            if mesh is None:
+                train = trainer._make_train(load)
+            else:
+                train_sharded = trainer._sharded_train_raw(use_prox, has_mask, load)
 
             def body(carry, x_t):
                 w, sc, abuf, stopped, last_acc = carry
@@ -259,19 +275,13 @@ class _ChunkRunner:
                     sc_new, slots, exploited = sc, host_slots, jnp.asarray(False)
                 slots = slots.astype(jnp.int32)
                 ids = cand[slots]
-                sel_sizes = (page_sizes if paged else sizes_f)[
-                    slots if paged else ids
-                ]
+                sel_sizes = page_sizes[slots if paged else ids]
 
-                # --- gather the cohort's padded batches ---------------------
+                # --- the cohort's batch schedule (gathered step by step) ----
                 if mesh is None:
                     bi = bi_t[slots]
-                    if paged:
-                        rows = slots[:, None, None]
-                        x, y = page_x[rows, bi], page_y[rows, bi]
-                    else:
-                        rows = ids[:, None, None]
-                        x, y = store.x[rows, bi], store.y[rows, bi]
+                    rows = (slots if paged else ids)[:, None, None]
+                    x, y = bi, jnp.broadcast_to(rows, bi.shape)
                     sw, sv = sw_t[slots], sv_t[slots]
                     mu = prox_t[slots]
                     _, flat, losses = train(
@@ -299,13 +309,12 @@ class _ChunkRunner:
                     bi = bi_t[slots_pad]
                     if paged:
                         rows = slots_pad[:, None, None]
-                        x, y = page_x[rows, bi], page_y[rows, bi]
                     else:
                         rows_ids = jax.lax.with_sharding_constraint(
                             cand[slots_pad], rep_sharding
                         )
                         rows = rows_ids[:, None, None]
-                        x, y = store.x[rows, bi], store.y[rows, bi]
+                    x, y = bi, jnp.broadcast_to(rows, bi.shape)
                     sw, sv = sw_t[slots_pad], sv_t[slots_pad]
                     if p_pad > p:
                         valid_row = (jnp.arange(p_pad) < p).astype(sv.dtype)
@@ -367,7 +376,17 @@ class _ChunkRunner:
                         ),
                         "anchor": abuf["anchor"].at[k_slot].set(w),
                     }
-                    buf_u = abuf["u"].reshape(b_depth * p, -1)
+                    # A one-slot ring (max_staleness=0) holds exactly the
+                    # departing cohort: read the update rows from ``flat``
+                    # itself.  Reading them back out of the just-updated
+                    # ring is the same arithmetic, but XLA compiles the
+                    # downstream Eq. 5/6 dots differently around the
+                    # dynamic_update_slice (Ω then drifts by 1 ulp from
+                    # the synchronous ingest).
+                    if b_depth == 1:
+                        buf_u = flat
+                    else:
+                        buf_u = abuf["u"].reshape(b_depth * p, -1)
                     buf_sizes = abuf["sizes"].reshape(-1)
                     buf_ids = abuf["ids"].reshape(-1)
                     buf_depart = abuf["depart"].reshape(-1)
@@ -475,36 +494,28 @@ class _ChunkRunner:
                 )
             return w, sc, abuf, stopped, last_acc, outs
 
-        if paged:
-            def chunk(w, sc, abuf, stopped, last_acc, cand, page_x, page_y,
-                      page_sizes, xs):
-                body = body_with(cand, page_x, page_y, page_sizes)
-                carry = jax.lax.scan(body, (w, sc, abuf, stopped, last_acc), xs)
-                return finish(*carry)
-        else:
-            def chunk(w, sc, abuf, stopped, last_acc, cand, xs):
-                body = body_with(cand, None, None, None)
-                carry = jax.lax.scan(body, (w, sc, abuf, stopped, last_acc), xs)
-                return finish(*carry)
+        def chunk(w, sc, abuf, stopped, last_acc, cand, page_x, page_y,
+                  page_sizes, eval_x, eval_y, xs):
+            body = body_with(cand, page_x, page_y, page_sizes, eval_x, eval_y)
+            carry = jax.lax.scan(body, (w, sc, abuf, stopped, last_acc), xs)
+            return finish(*carry)
 
         # donated carry: the chunk's (D[,_pad]) flat model, the strategy
         # carry (FLrce's Ω/H and the V/A maps), the async arrival buffer (an
         # empty pytree on synchronous jobs), the cross-chunk stop flag and
         # the accuracy scalar alias their outputs — no per-chunk copy of the
-        # O(M·D) state.  The candidate remap and (paged) page tensors are
-        # fresh per-chunk inputs and are NOT donated: at pipeline depth 2 the
-        # two in-flight chunks each hold their own page.
+        # O(M·D) state.  The candidate remap, the page (or resident store)
+        # and the eval set are NOT donated: at pipeline depth 2 the two
+        # in-flight chunks each hold their own page, and the store and eval
+        # set serve every chunk.
         return jax.jit(chunk, donate_argnums=(0, 1, 2, 3, 4))
 
     def run_chunk(self, w, sc, abuf, stopped, last_acc, cand, page, xs,
                   use_prox: bool, has_mask: bool):
         key = (use_prox, has_mask)
-        if self.paged:
-            page_x, page_y, page_sizes = page
-            args = (w, sc, abuf, stopped, last_acc, cand, page_x, page_y,
-                    page_sizes, xs)
-        else:
-            args = (w, sc, abuf, stopped, last_acc, cand, xs)
+        page_x, page_y, page_sizes = page if self.paged else self._resident
+        args = (w, sc, abuf, stopped, last_acc, cand, page_x, page_y,
+                page_sizes, self.eval_x, self.eval_y, xs)
         if key not in self._cache:
             shardings = None
             if self.mesh is not None:
@@ -675,7 +686,7 @@ def run_scan_driver(
         learning_rate=learning_rate, batch_size=batch_size,
         clients_per_round=strategy.p, eval_every=eval_every,
         max_rounds=max_rounds,
-        eval_x=jnp.asarray(dataset.eval_x), eval_y=jnp.asarray(dataset.eval_y),
+        eval_x=dataset.eval_x, eval_y=dataset.eval_y,
         mesh=mesh, paged=paged, async_plan=async_plan,
     )
 

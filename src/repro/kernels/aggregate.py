@@ -12,14 +12,14 @@ the roofline for Eq. 4 dictates.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax>=0.5 renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels import default_interpret
 
 DEFAULT_BLOCK_D = 4096
 
@@ -40,12 +40,15 @@ def weighted_aggregate(
     weights: jax.Array,
     *,
     block_d: int = DEFAULT_BLOCK_D,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Eq. 4: ``w + weights @ updates`` with one fused pass over HBM.
 
     w: (D,), updates: (P, D), weights: (P,).  Returns fp32 (D,).
+    ``interpret=None`` resolves from the detected JAX backend.
     """
+    if interpret is None:
+        interpret = default_interpret()
     (d,) = w.shape
     p, du = updates.shape
     if du != d:
@@ -66,6 +69,6 @@ def weighted_aggregate(
         out_specs=pl.BlockSpec((1, block_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, dp), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
     )(w.reshape(1, dp), updates, weights.reshape(1, p))
     return out[0, :d]

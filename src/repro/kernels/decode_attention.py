@@ -15,14 +15,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax>=0.5 renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels import default_interpret
 
 DEFAULT_BLOCK_S = 512
 _NEG_INF = -1e30
@@ -90,9 +90,13 @@ def decode_attention(
     length: jax.Array,   # (B,) int32 valid lengths
     *,
     block_s: int = DEFAULT_BLOCK_S,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """One-token GQA attention over a blocked KV cache.  Returns (B, H, hd)."""
+    """One-token GQA attention over a blocked KV cache.  Returns (B, H, hd).
+
+    ``interpret=None`` resolves from the detected JAX backend."""
+    if interpret is None:
+        interpret = default_interpret()
     b, h, hd = q.shape
     s, kv = k_cache.shape[1], k_cache.shape[2]
     if h % kv:
@@ -122,5 +126,5 @@ def decode_attention(
             pltpu.VMEM((kv, group, hd), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
     )(length.reshape(b, 1).astype(jnp.int32), q, k_cache, v_cache)

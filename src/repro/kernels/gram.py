@@ -22,21 +22,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax>=0.5 renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels import default_interpret
 
 DEFAULT_BLOCK_D = 2048
-
-
-def default_interpret() -> bool:
-    """Compile through Mosaic only on TPU; interpret everywhere else.
-
-    These kernels carry TPU compiler params (and TPU memory spaces), so only
-    the TPU backend can compile them; on CPU/GPU the interpreter — which
-    still jit-lowers to XLA and validates the exact blocked algorithm — is
-    the correct default.
-    """
-    return jax.default_backend() != "tpu"
+# rows of V per cross_gram tile: the lane (last) dim of the (K, BLOCK_M)
+# output tile, so a multiple of 128; a (512, 2048) fp32 V tile is 4 MiB
+BLOCK_M = 512
 
 
 def _gram_kernel(u_ref, out_ref):
@@ -77,12 +68,12 @@ def gram(
         out_specs=pl.BlockSpec((p, p), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((p, p), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
     )(u)
 
 
-def _xgram_kernel(u_ref, v_ref, out_ref):
-    step = pl.program_id(0)
+def _xgram_kernel(u_ref, v_ref, out_ref, *, d: int, block_d: int):
+    step = pl.program_id(1)                      # D is the reduction axis
 
     @pl.when(step == 0)
     def _init():
@@ -90,6 +81,11 @@ def _xgram_kernel(u_ref, v_ref, out_ref):
 
     u = u_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)
+    if d % block_d:
+        # the last D tile overhangs the arrays: zero the columns past d
+        col = step * block_d + jax.lax.broadcasted_iota(jnp.int32, (1, block_d), 1)
+        u = jnp.where(col < d, u, 0.0)
+        v = jnp.where(col < d, v, 0.0)
     out_ref[...] += jax.lax.dot_general(
         u, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -100,29 +96,36 @@ def cross_gram(
     u: jax.Array, v: jax.Array, *, block_d: int = DEFAULT_BLOCK_D,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """Cross Gram ``u @ v.T`` for (P, D) x (Q, D) — used by asynchronous RM
-    (dots of fresh updates against the stored update/anchor maps).
-    ``interpret=None`` resolves from the detected JAX backend."""
+    """Cross Gram ``u @ v.T`` for (K, D) x (M, D) — the relationship block's
+    dots of fresh updates against the stored update/anchor maps.
+
+    The grid tiles both the rows of V (``BLOCK_M``, "parallel") and D
+    ("arbitrary", the reduction), so VMEM holds one ``(BLOCK_M, block_d)``
+    tile of V whatever M is.  Neither operand is padded: V is the (M, D)
+    map, and a padded copy of it would double its HBM footprint.  The
+    overhanging tail of the last D tile is masked to zero in the kernel,
+    and a ragged last row tile only produces output columns that are never
+    written back.  ``interpret=None`` resolves from the detected JAX
+    backend.
+    """
     if interpret is None:
         interpret = default_interpret()
     if u.shape[1] != v.shape[1]:
         raise ValueError(f"dim mismatch {u.shape} vs {v.shape}")
-    p, d = u.shape
-    q = v.shape[0]
-    pad = (-d) % block_d
-    if pad:
-        u = jnp.pad(u, ((0, 0), (0, pad)))
-        v = jnp.pad(v, ((0, 0), (0, pad)))
-    grid = ((d + pad) // block_d,)
+    k, d = u.shape
+    m = v.shape[0]
+    bm = min(m, BLOCK_M)
     return pl.pallas_call(
-        _xgram_kernel,
-        grid=grid,
+        functools.partial(_xgram_kernel, d=d, block_d=block_d),
+        grid=(pl.cdiv(m, bm), pl.cdiv(d, block_d)),
         in_specs=[
-            pl.BlockSpec((p, block_d), lambda i: (0, i)),
-            pl.BlockSpec((q, block_d), lambda i: (0, i)),
+            pl.BlockSpec((k, block_d), lambda i, j: (0, j)),
+            pl.BlockSpec((bm, block_d), lambda i, j: (i, j)),
         ],
-        out_specs=pl.BlockSpec((p, q), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((p, q), jnp.float32),
+        out_specs=pl.BlockSpec((k, bm), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((k, m), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
     )(u, v)

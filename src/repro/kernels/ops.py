@@ -1,67 +1,22 @@
-"""Jit'd public wrappers for every Pallas kernel.
+"""Public entry points for every Pallas kernel.
 
-``interpret`` defaults from the detected JAX backend: compiled through Mosaic
-on TPU, interpreted (the kernel body traces to XLA ops, validating the exact
-blocked algorithm) on CPU/GPU — the kernels carry TPU compiler params, so
-only the TPU backend can compile them.  ``REPRO_PALLAS_INTERPRET=0|1``
-overrides the detection either way.
+Each resolves ``interpret=None`` from the detected JAX backend (see
+:func:`repro.kernels.default_interpret`): compiled through Mosaic on TPU,
+interpreted (the kernel body traces to XLA ops, validating the exact blocked
+algorithm) on CPU/GPU.
 """
 from __future__ import annotations
 
-import os
+from repro.kernels.aggregate import weighted_aggregate
+from repro.kernels.decode_attention import decode_attention
+from repro.kernels.gram import cross_gram, gram
+from repro.kernels.topk_mask import topk_mask, topk_mask_rows
 
-import jax
-
-from repro.kernels import aggregate as _aggregate
-from repro.kernels import decode_attention as _decode_attention
-from repro.kernels import gram as _gram
-from repro.kernels import topk_mask as _topk_mask
-
-
-def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env != "0"
-    return _gram.default_interpret()
-
-
-def gram(u: jax.Array, *, block_d: int = _gram.DEFAULT_BLOCK_D) -> jax.Array:
-    return _gram.gram(u, block_d=block_d, interpret=_interpret())
-
-
-def cross_gram(u: jax.Array, v: jax.Array, *, block_d: int = _gram.DEFAULT_BLOCK_D) -> jax.Array:
-    return _gram.cross_gram(u, v, block_d=block_d, interpret=_interpret())
-
-
-def weighted_aggregate(
-    w: jax.Array, updates: jax.Array, weights: jax.Array,
-    *, block_d: int = _aggregate.DEFAULT_BLOCK_D,
-) -> jax.Array:
-    return _aggregate.weighted_aggregate(
-        w, updates, weights, block_d=block_d, interpret=_interpret()
-    )
-
-
-def topk_mask(
-    u: jax.Array, *, keep_frac: float = 0.1, block_d: int = _topk_mask.DEFAULT_BLOCK_D
-) -> jax.Array:
-    return _topk_mask.topk_mask(
-        u, keep_frac=keep_frac, block_d=block_d, interpret=_interpret()
-    )
-
-
-def topk_mask_rows(
-    u: jax.Array, *, keep_frac: float = 0.1, block_d: int = _topk_mask.DEFAULT_BLOCK_D
-) -> jax.Array:
-    return _topk_mask.topk_mask_rows(
-        u, keep_frac=keep_frac, block_d=block_d, interpret=_interpret()
-    )
-
-
-def decode_attention(
-    q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, length: jax.Array,
-    *, block_s: int = _decode_attention.DEFAULT_BLOCK_S,
-) -> jax.Array:
-    return _decode_attention.decode_attention(
-        q, k_cache, v_cache, length, block_s=block_s, interpret=_interpret()
-    )
+__all__ = [
+    "cross_gram",
+    "decode_attention",
+    "gram",
+    "topk_mask",
+    "topk_mask_rows",
+    "weighted_aggregate",
+]

@@ -8,19 +8,27 @@ DCN-connected data-parallel replica axis.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the shard_map and
+    ``with_sharding_constraint`` code of the round engine is written for
+    compiler-propagated (not explicit) shardings."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, *, pod: int = 0):
     """Small mesh for in-process tests (requires >= data*model host devices)."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _auto_mesh((pod, data, model), ("pod", "data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_engine_mesh(data: int | None = None, model: int | None = None):
@@ -46,4 +54,4 @@ def make_engine_mesh(data: int | None = None, model: int | None = None):
         model = n // data
     if data * model > n:
         raise ValueError(f"mesh ({data}, {model}) needs {data * model} devices, have {n}")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

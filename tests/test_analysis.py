@@ -662,6 +662,59 @@ def test_scan_driver_reports_single_chunk_compile():
     assert res.driver_stats["compiles_total"] >= 1
 
 
+_CACHED_JOB = """
+import json
+from repro.data import make_federated_classification
+from repro.fl import run_federated
+from repro.fl.baselines import FedAvg
+from repro.launch import compile_cache
+from repro.models.cnn import MLPClassifier
+
+path = compile_cache()
+ds = make_federated_classification(
+    num_clients=6, alpha=0.5, num_samples=480, num_eval=96,
+    feature_dim=8, num_classes=3, seed=0,
+)
+res = run_federated(
+    MLPClassifier(feature_dim=8, num_classes=3, hidden=(16,)), ds,
+    FedAvg(6, 3, 1, seed=0), max_rounds=6, learning_rate=0.1,
+    batch_size=16, seed=0, driver="scan", scan_chunk_rounds=3,
+)
+print(json.dumps({"path": path, "compiles_chunk": res.driver_stats["compiles_chunk"]}))
+"""
+
+
+def test_chunk_compile_counts_once_on_cold_and_warm_cache(tmp_path):
+    """A program loaded from the persistent cache fires no backend-compile
+    event; the sentinel counts the load, so a warm process still reports
+    exactly one chunk program (in its own process: the cache is global)."""
+    import json
+
+    cache = tmp_path / "jax_cache"
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_COMPILATION_CACHE_DIR=str(cache))
+    runs = []
+    for _ in range(2):
+        proc = subprocess.run([sys.executable, "-c", _CACHED_JOB], cwd=tmp_path,
+                              env=env, capture_output=True, text=True)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        runs.append(json.loads(proc.stdout.strip().splitlines()[-1]))
+        assert any(cache.iterdir())
+    assert runs[0] == runs[1] == {"path": str(cache), "compiles_chunk": 1}
+
+
+def test_compile_cache_defaults_to_checkout_dir(tmp_path):
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    code = ("import jax; from repro.launch import compile_cache; p = compile_cache(); "
+            "print(p, jax.config.jax_compilation_cache_dir)")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=tmp_path, env=env,
+                          capture_output=True, text=True)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    want = os.path.join(REPO, ".jax_cache")
+    assert proc.stdout.split() == [want, want]
+
+
 # ---------------------------------------------------------------------------
 # docs sync: docs/invariants.md rule table ≡ code
 # ---------------------------------------------------------------------------

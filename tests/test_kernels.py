@@ -27,7 +27,10 @@ def test_gram_matches_ref(p, d, dtype):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-2 if dtype == jnp.bfloat16 else 2e-4, atol=1e-2 if dtype == jnp.bfloat16 else 1e-4)
 
 
-@pytest.mark.parametrize("p,q,d", [(4, 6, 512), (8, 8, 3000)])
+@pytest.mark.parametrize("p,q,d", [
+    (4, 6, 512), (8, 8, 3000),
+    (10, 700, 5000),   # two row tiles of V (the last ragged) and a ragged D tile
+])
 def test_cross_gram_matches_ref(p, q, d):
     rng = np.random.default_rng(p + q)
     u = _rand(rng, (p, d), jnp.float32)
@@ -69,6 +72,28 @@ def test_topk_mask_matches_ref(d, keep, block):
     got = ops.topk_mask(u, keep_frac=keep, block_d=block)
     want = ref.topk_mask_ref(u, keep_frac=keep, block_d=block)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("p,d,keep,block,kind", [
+    (5, 700, 0.1, 256, "normal"),       # P and D both padded
+    (8, 4096, 0.25, 1024, "normal"),
+    (3, 2048, 0.1, 2048, "ties"),       # many equal magnitudes, ±0.0
+    (10, 3000, 0.05, 512, "bf16"),
+])
+def test_topk_mask_rows_bitwise_matches_ref(p, d, keep, block, kind):
+    """The bisection threshold is the exact k-th magnitude: every row is
+    bitwise the lax.top_k oracle, ties at the threshold included."""
+    rng = np.random.default_rng(p * d)
+    if kind == "ties":
+        u = jnp.asarray(rng.integers(-3, 4, size=(p, d)), jnp.float32)
+        u = u.at[0, :10].set(-0.0)
+    else:
+        dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
+        u = _rand(rng, (p, d), dtype)
+    got = np.asarray(ops.topk_mask_rows(u, keep_frac=keep, block_d=block))
+    for i in range(p):
+        want = np.asarray(ref.topk_mask_ref(u[i], keep_frac=keep, block_d=block))
+        np.testing.assert_array_equal(got[i], want)
 
 
 @pytest.mark.parametrize("b,h,kv,hd,s,block", [
