@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st
+from hypothesis import assume, given, settings, strategies as st
 
 from repro.core import cossim, orthdist, relationship_row, select_clients, should_stop
 from repro.data.partition import dirichlet_label_partition
@@ -39,7 +39,11 @@ def test_cossim_symmetric_and_bounded(a, b):
 @settings(max_examples=30, deadline=None)
 @given(finite_vec, st.floats(0.1, 100.0))
 def test_cossim_scale_invariant(a, s):
+    """Holds wherever ``||u||·||s·u||`` clears cossim's 1e-12 zero-vector
+    guard; below it the guard damps the cosine toward 0 by design (e.g.
+    ``u = [0, 2**-24]``), so such vectors are outside the property."""
     u = jnp.asarray(a)
+    assume(float(jnp.vdot(u, u)) * min(s, 1.0) > 1e-9)
     assert float(cossim(u, u * s)) == pytest.approx(float(cossim(u, u)), abs=1e-4)
 
 
