@@ -215,7 +215,8 @@ class HostClientStore:
         work even at M ≥ 10⁵ clients.
 
         Each sample's features are stored flattened to one trailing axis of
-        F values (models reshape what they need, e.g. ``PaperCNN``): a TPU
+        F values (the compiled chunk restores the dataset's feature shape
+        as it loads a batch): a TPU
         tiles the two minor dims of an array by (8, 128), so an image
         store's (W, C) = (32, 3) minor dims would take 4x their bytes in
         HBM, and so would every batch gathered from it.

@@ -328,10 +328,9 @@ class BatchedCohortTrainer:
 
         ``load`` maps one step's scanned ``(xs, ys)`` entries to that step's
         ``(x, y)`` batch; ``None`` scans materialized batches.  The compiled
-        chunks scan sample positions and client rows and gather each step's
-        batch from the device store, so a round never holds its whole
-        (P, S, B, F) batch tensor (1.9 GB at the paper's CIFAR width, 4x
-        that in the TPU's tiled layout).
+        chunks use it to restore the store's flat samples to the model's
+        feature shape and, where the store is not split over devices, to
+        gather each step's batch from it (scan_driver).
         """
         model, lr = self.model, self.lr
 
@@ -475,7 +474,7 @@ class ShardedCohortTrainer(BatchedCohortTrainer):
     def _sharded_train_raw(self, use_prox: bool, has_mask: bool, load=None):
         """The bare mesh cohort program (not jitted) — the form the compiled
         round chunks trace straight into their scan body (with ``load``, see
-        :meth:`_make_train`: it closes over the chunk's traced store, so
+        :meth:`_make_train`: it may close over the chunk's traced store, so
         that program is built per trace and not cached).
 
         Replicated-model path: shard_map the cohort program over ``data``

@@ -175,9 +175,12 @@ class _ChunkRunner:
     def __init__(self, model, store: Optional[DeviceClientStore], unflatten,
                  program, transform, *, learning_rate: float, batch_size: int,
                  clients_per_round: int, eval_every: int, max_rounds: int,
-                 eval_x, eval_y, mesh=None, paged: bool = False,
-                 async_plan=None):
+                 eval_x, eval_y, feat_shape: Tuple[int, ...], mesh=None,
+                 paged: bool = False, async_plan=None):
         self.model = model
+        # the stores keep each sample's features flat (F values); batches
+        # are restored to the dataset's feature shape as they are loaded
+        self.feat_shape = tuple(feat_shape)
         # staleness-aware rounds: None ⇒ synchronous chunks (the arrival
         # buffer carry slot is an empty pytree and the body is untouched)
         self.async_plan = async_plan
@@ -197,6 +200,7 @@ class _ChunkRunner:
         if mesh is None:
             self._trainer = BatchedCohortTrainer(model, learning_rate, batch_size)
             self.p_pad = clients_per_round
+            self.n_data = 1
         else:
             self._trainer = ShardedCohortTrainer(model, learning_rate, batch_size, mesh)
             self.axes = self._trainer.axes
@@ -224,6 +228,14 @@ class _ChunkRunner:
         paged, async_plan = self.paged, self.async_plan
         eval_every, max_rounds = self.eval_every, self.max_rounds
         model, trainer = self.model, self._trainer
+        feat_shape = self.feat_shape
+        # where the store is not split over a ``data`` axis, the trainer
+        # gathers one step's batch at a time from it, so a round never holds
+        # its whole (P, S, B, F) batch tensor (1.9 GB at the paper's CIFAR
+        # width).  A store sharded over ``data`` is instead gathered once per
+        # round through the partitioner: the shard_mapped trainer would see
+        # it whole and all-gather it onto every device.
+        step_gather = self.n_data == 1
         eval_params = self.unflatten
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -250,12 +262,20 @@ class _ChunkRunner:
             recovers global ids for sizes, the update transform and the
             strategy carry.  Paged mode gathers samples from the page by
             slot; resident mode's "page" is the full store, gathered by
-            global id.  The trainer gathers one step's batch at a time
-            (``load``): it scans sample positions and client rows.
+            global id.  With ``step_gather`` the trainer scans sample
+            positions and client rows and gathers each step's batch
+            (``load``); otherwise it scans the round's gathered batches.
             """
 
-            def load(pos, rows):
-                return page_x[rows, pos], page_y[rows, pos]
+            def unflat(x):
+                return x.reshape(*x.shape[:-1], *feat_shape)
+
+            if step_gather:
+                def load(pos, rows):
+                    return unflat(page_x[rows, pos]), page_y[rows, pos]
+            else:
+                def load(x, y):
+                    return unflat(x), y
 
             if mesh is None:
                 train = trainer._make_train(load)
@@ -314,7 +334,10 @@ class _ChunkRunner:
                             cand[slots_pad], rep_sharding
                         )
                         rows = rows_ids[:, None, None]
-                    x, y = bi, jnp.broadcast_to(rows, bi.shape)
+                    if step_gather:
+                        x, y = bi, jnp.broadcast_to(rows, bi.shape)
+                    else:
+                        x, y = page_x[rows, bi], page_y[rows, bi]
                     sw, sv = sw_t[slots_pad], sv_t[slots_pad]
                     if p_pad > p:
                         valid_row = (jnp.arange(p_pad) < p).astype(sv.dtype)
@@ -376,17 +399,7 @@ class _ChunkRunner:
                         ),
                         "anchor": abuf["anchor"].at[k_slot].set(w),
                     }
-                    # A one-slot ring (max_staleness=0) holds exactly the
-                    # departing cohort: read the update rows from ``flat``
-                    # itself.  Reading them back out of the just-updated
-                    # ring is the same arithmetic, but XLA compiles the
-                    # downstream Eq. 5/6 dots differently around the
-                    # dynamic_update_slice (Ω then drifts by 1 ulp from
-                    # the synchronous ingest).
-                    if b_depth == 1:
-                        buf_u = flat
-                    else:
-                        buf_u = abuf["u"].reshape(b_depth * p, -1)
+                    buf_u = abuf["u"].reshape(b_depth * p, -1)
                     buf_sizes = abuf["sizes"].reshape(-1)
                     buf_ids = abuf["ids"].reshape(-1)
                     buf_depart = abuf["depart"].reshape(-1)
@@ -687,6 +700,7 @@ def run_scan_driver(
         clients_per_round=strategy.p, eval_every=eval_every,
         max_rounds=max_rounds,
         eval_x=dataset.eval_x, eval_y=dataset.eval_y,
+        feat_shape=dataset.x.shape[1:],
         mesh=mesh, paged=paged, async_plan=async_plan,
     )
 

@@ -83,9 +83,8 @@ class MLPClassifier:
 class PaperCNN:
     """2 conv layers + ``num_fc`` dense layers (paper §4.1 models).
 
-    input: (N, H, W, C) images, or the same flattened to (N, H·W·C) (the
-    compiled driver's client store keeps samples flat).  conv 5x5/32 ->
-    maxpool2 -> conv 5x5/64 -> maxpool2 -> fc stack.
+    input: (N, H, W, C) images.  conv 5x5/32 -> maxpool2 -> conv 5x5/64 ->
+    maxpool2 -> fc stack.
     """
 
     side: int
@@ -123,7 +122,7 @@ class PaperCNN:
         )
 
     def logits(self, params, x: jax.Array) -> jax.Array:
-        h = x.reshape(x.shape[0], self.side, self.side, self.channels)
+        h = x
         h = self._conv_block(params["conv1"], h)
         h = self._conv_block(params["conv2"], h)
         h = h.reshape(h.shape[0], -1)

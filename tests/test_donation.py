@@ -56,6 +56,7 @@ def test_chunk_carry_donated_in_place(tiny_fed):
         learning_rate=0.1, batch_size=16, clients_per_round=3,
         eval_every=1, max_rounds=2,
         eval_x=jnp.asarray(ds.eval_x), eval_y=jnp.asarray(ds.eval_y),
+        feat_shape=ds.x.shape[1:],
     )
     r, m = 2, 8
     sched = build_chunk_schedule(

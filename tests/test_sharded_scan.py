@@ -248,3 +248,91 @@ def test_store_shard_matches_from_dataset_mesh(tiny_fed):
     np.testing.assert_array_equal(np.asarray(a.y), np.asarray(b.y))
     assert a.x.sharding == b.x.sharding
     assert a.num_clients == b.num_clients == 8
+
+
+# ---------------------------------------------------------------------------
+# image samples: the store keeps them flat, the chunk restores their shape
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def cnn_fed():
+    from repro.data import make_image_like
+    from repro.models.cnn import PaperCNN
+
+    ds = make_image_like(num_clients=6, num_samples=360, num_eval=60,
+                         side=8, channels=1, num_classes=3, seed=0)
+    model = PaperCNN(side=8, channels=1, num_classes=3, num_fc=2,
+                     conv_channels=(4, 8), fc_width=16)
+    return ds, model
+
+
+def test_sharded_scan_image_samples_default_mesh(cnn_fed):
+    """(1, 1) mesh: each step's batch is gathered from the flat store and
+    reshaped to (B, H, W, C) inside the step scan."""
+    ds, model = cnn_fed
+    dim = param_count(model.init(jax.random.PRNGKey(0)))
+    loo, scn = _run_both(
+        model, ds, lambda: FLrce(6, 3, 1, dim=dim, es_threshold=2.0, seed=0),
+        max_rounds=3, learning_rate=0.05, batch_size=16, seed=0, chunk=2,
+    )
+    _assert_records_match(loo, scn)
+
+
+@needs8
+def test_sharded_scan_image_samples_8dev(cnn_fed, mesh8):
+    """(2, 4) mesh: the round's batches are gathered from the data-sharded
+    flat store before the shard_mapped trainer, then reshaped per step."""
+    ds, model = cnn_fed
+    dim = param_count(model.init(jax.random.PRNGKey(0)))
+    loo, scn = _run_both(
+        model, ds, lambda: FLrce(6, 3, 1, dim=dim, es_threshold=2.0, seed=0),
+        mesh=mesh8, max_rounds=3, learning_rate=0.05, batch_size=16, seed=0,
+        chunk=2,
+    )
+    _assert_records_match(loo, scn)
+
+
+_STORE_GATHER_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json, re
+import jax
+from repro.data import make_federated_classification
+from repro.fl import FLrce, run_federated, scan_driver
+from repro.launch.mesh import make_debug_mesh
+from repro.models.cnn import MLPClassifier, param_count
+
+ds = make_federated_classification(num_clients=8, alpha=0.2, num_samples=800,
+                                   num_eval=160, feature_dim=8, num_classes=3, seed=2)
+model = MLPClassifier(feature_dim=8, num_classes=3, hidden=(16,))
+dim = param_count(model.init(jax.random.PRNGKey(0)))
+n_max = int(ds.client_sizes().max())
+scan_driver._hlo_capture = hlo = []
+run_federated(model, ds, FLrce(8, 3, 1, dim=dim, es_threshold=2.0, seed=0),
+              engine="sharded", driver="scan", mesh=make_debug_mesh(2, 2),
+              max_rounds=2, scan_chunk_rounds=2, learning_rate=0.1,
+              batch_size=16, seed=0)
+gathers = [l for l in hlo[0].splitlines() if re.search(r"all-gather(-start)?\(", l)]
+whole = [l for l in gathers if re.search(rf"\[8,{n_max}(,\d+)?\]", l)]
+print(json.dumps({"hlo": len(hlo), "store_gathers": whole}))
+"""
+
+
+def test_sharded_scan_never_gathers_the_whole_store_subprocess():
+    """On a (2, 2) mesh the client store stays split over ``data``: the
+    compiled chunk gathers the round's batches from it and never
+    all-gathers the (M, N_max, …) store onto every device."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, "-c", _STORE_GATHER_SCRIPT], env=env,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-4000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    assert got == {"hlo": 1, "store_gathers": []}
