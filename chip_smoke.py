@@ -172,7 +172,9 @@ def one_chip(clock):
           f"steady_s_per_round={per_round} peak_bytes_in_use={peak}")
     st = res.driver_stats
     print("driver_stats: " + " ".join(
-        f"{k}={st[k]}" for k in ("host_build_s", "device_wait_s", "host_flush_s",
+        f"{k}={st[k]}" for k in ("setup_store_s", "host_build_s", "dispatch_s",
+                                 "compile_s", "device_wait_s", "host_flush_s",
+                                 "steps_useful", "steps_executed",
                                  "schedule_bytes_host", "chunks")))
     return problems
 

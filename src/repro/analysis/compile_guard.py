@@ -16,6 +16,10 @@ persistent cache::
         run_federated(..., driver="scan")
     assert cc.compiles == expected
 
+Each counter also sums the events' durations (``seconds``): the scan
+driver reports that sum as ``compile_s``, so a compile inside a chunk
+dispatch is booked as set-up and not as host work.
+
 ``jax.monitoring`` has no public unregister, so a single module-level
 dispatcher is registered once (lazily, on first use) and forwards to
 whichever counters are active; exiting a ``CompileCounter`` just removes
@@ -40,6 +44,7 @@ def _dispatch(event: str, duration: float, **kwargs) -> None:
     with _lock:
         for counter in _active:
             counter._count += 1
+            counter._seconds += duration
 
 
 def _ensure_registered() -> None:
@@ -55,19 +60,25 @@ def _ensure_registered() -> None:
 
 class CompileCounter:
     """Context manager counting XLA backend compiles (or persistent-cache
-    loads) inside its block."""
+    loads) inside its block, and the seconds they took."""
 
     def __init__(self) -> None:
         self._count = 0
+        self._seconds = 0.0
 
     @property
     def compiles(self) -> int:
         return self._count
 
+    @property
+    def seconds(self) -> float:
+        return self._seconds
+
     def __enter__(self) -> "CompileCounter":
         _ensure_registered()
         with _lock:
             self._count = 0
+            self._seconds = 0.0
             _active.append(self)
         return self
 

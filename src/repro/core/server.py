@@ -349,6 +349,9 @@ class FLrceServer:
         )
         return {**carry, "rng": rng}, slots, exploited
 
+    # the scan forms run under named scopes (``fl/ingest``, ``fl/early_stop``):
+    # their device ops carry the phase in the profiler's ``tf_op``
+    @jax.named_scope("fl/ingest")
     def scan_ingest(
         self,
         carry: Dict[str, jax.Array],
@@ -406,6 +409,7 @@ class FLrceServer:
             "last_round": last_round,
         }
 
+    @jax.named_scope("fl/early_stop")
     def scan_check_early_stop(
         self,
         carry: Dict[str, jax.Array],
@@ -467,6 +471,7 @@ class FLrceServer:
     # row arriving in its departure round (max_staleness=0) both are bitwise
     # their synchronous counterparts — the equivalence the async harness pins.
 
+    @jax.named_scope("fl/ingest")
     def scan_ingest_async(
         self,
         carry: Dict[str, jax.Array],
@@ -539,6 +544,7 @@ class FLrceServer:
             "last_round": last_round,
         }
 
+    @jax.named_scope("fl/early_stop")
     def scan_check_early_stop_async(
         self,
         carry: Dict[str, jax.Array],

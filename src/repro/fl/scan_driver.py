@@ -74,6 +74,17 @@ synchronous chunk bitwise — records, ledger and written-back strategy state
 (tests/test_async_rounds.py).  All round-index arithmetic on the buffers
 goes through ``repro.fl.async_rounds.staleness_of`` (flcheck FLC007).
 
+**Spans and counters**: every host phase of the chunk loop is one span —
+a profiler ``TraceAnnotation`` named ``fl.<phase>`` (``fl.build`` and its
+``fl.build.<step>`` phases, ``fl.dispatch``, ``fl.wait``, ``fl.flush``)
+with the chunk's first round as its ``chunk`` stat, whose seconds also
+feed ``driver_stats``; with no trace running it records nothing.  The
+scan body's phases run under ``jax.named_scope("fl/<phase>")``, which
+reaches each device op's ``tf_op`` in a trace and changes no op.  Each
+round outputs its cohort's real local steps; the flush sums them into
+``driver_stats["steps_useful"]`` beside ``steps_executed`` (rounds ×
+padded cohort × step bucket).
+
 Strategies opt in via ``Strategy.supports_scan`` / ``scan_program()`` — FLrce
 and every §4.1 baseline except PyramidFL, whose loss-driven selection/epoch
 plan cannot be precomputed; the mesh-sharded chunks additionally require
@@ -83,6 +94,7 @@ plan cannot be precomputed; the mesh-sharded chunks additionally require
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -289,81 +301,89 @@ class _ChunkRunner:
 
                 # --- Alg. 2 selection (device, candidate-relative slots) ----
                 # or host-precomputed slots --------------------------------
-                if program.select is not None:
-                    sc_new, slots, exploited = program.select(sc, t, phi, cand)
-                else:
-                    sc_new, slots, exploited = sc, host_slots, jnp.asarray(False)
-                slots = slots.astype(jnp.int32)
-                ids = cand[slots]
-                sel_sizes = page_sizes[slots if paged else ids]
+                with jax.named_scope("fl/select"):
+                    if program.select is not None:
+                        sc_new, slots, exploited = program.select(sc, t, phi, cand)
+                    else:
+                        sc_new, slots, exploited = sc, host_slots, jnp.asarray(False)
+                    slots = slots.astype(jnp.int32)
+                    ids = cand[slots]
+                    sel_sizes = page_sizes[slots if paged else ids]
 
                 # --- the cohort's batch schedule (gathered step by step) ----
-                if mesh is None:
-                    bi = bi_t[slots]
-                    rows = (slots if paged else ids)[:, None, None]
-                    x, y = bi, jnp.broadcast_to(rows, bi.shape)
-                    sw, sv = sw_t[slots], sv_t[slots]
-                    mu = prox_t[slots]
-                    _, flat, losses = train(
-                        params_t, x, y, sw, sv, mask_t, freeze_t, mu,
-                        use_prox=use_prox, has_mask=has_mask,
-                    )
-                else:
-                    # pad the cohort to the data axis with exact no-op clients
-                    # (zero step validity ⇒ identically-zero update rows), train
-                    # shard_mapped over it, then do the ONE pad-then-all-to-all
-                    # reshard to the (P, D_pad) D-sharded round-buffer layout
-                    # the O(P) index vectors MUST stay replicated: letting the
-                    # partitioner row-shard them over ``data`` miscompiles the
-                    # downstream store/schedule gathers (wrong rows, observed on
-                    # 2x4 CPU meshes) — a with_sharding_constraint pins them
-                    if p_pad > p:
-                        slots_pad = jnp.concatenate(
-                            [slots, jnp.zeros((p_pad - p,), jnp.int32)]
-                        )
-                    else:
-                        slots_pad = slots
-                    slots_pad = jax.lax.with_sharding_constraint(
-                        slots_pad, rep_sharding
-                    )
-                    bi = bi_t[slots_pad]
-                    if paged:
-                        rows = slots_pad[:, None, None]
-                    else:
-                        rows_ids = jax.lax.with_sharding_constraint(
-                            cand[slots_pad], rep_sharding
-                        )
-                        rows = rows_ids[:, None, None]
-                    if step_gather:
+                with jax.named_scope("fl/train"):
+                    if mesh is None:
+                        bi = bi_t[slots]
+                        rows = (slots if paged else ids)[:, None, None]
                         x, y = bi, jnp.broadcast_to(rows, bi.shape)
+                        sw, sv = sw_t[slots], sv_t[slots]
+                        mu = prox_t[slots]
+                        _, flat, losses = train(
+                            params_t, x, y, sw, sv, mask_t, freeze_t, mu,
+                            use_prox=use_prox, has_mask=has_mask,
+                        )
                     else:
-                        x, y = page_x[rows, bi], page_y[rows, bi]
-                    sw, sv = sw_t[slots_pad], sv_t[slots_pad]
-                    if p_pad > p:
-                        valid_row = (jnp.arange(p_pad) < p).astype(sv.dtype)
-                        sv = sv * valid_row[:, None]
-                    mu = prox_t[slots_pad]
-                    _, flat, losses = train_sharded(
-                        params_t, x, y, sw, sv, mask_t, freeze_t, mu
-                    )
-                    flat = trainer.reshard_rows_traced(flat, p)
-                    losses, sv = losses[:p], sv[:p]
+                        # pad the cohort to the data axis with exact no-op
+                        # clients (zero step validity ⇒ identically-zero update
+                        # rows) and train shard_mapped over it.  The O(P) index
+                        # vectors MUST stay replicated: letting the partitioner
+                        # row-shard them over ``data`` miscompiles the
+                        # downstream store/schedule gathers (wrong rows,
+                        # observed on 2x4 CPU meshes) — a
+                        # with_sharding_constraint pins them
+                        if p_pad > p:
+                            slots_pad = jnp.concatenate(
+                                [slots, jnp.zeros((p_pad - p,), jnp.int32)]
+                            )
+                        else:
+                            slots_pad = slots
+                        slots_pad = jax.lax.with_sharding_constraint(
+                            slots_pad, rep_sharding
+                        )
+                        bi = bi_t[slots_pad]
+                        if paged:
+                            rows = slots_pad[:, None, None]
+                        else:
+                            rows_ids = jax.lax.with_sharding_constraint(
+                                cand[slots_pad], rep_sharding
+                            )
+                            rows = rows_ids[:, None, None]
+                        if step_gather:
+                            x, y = bi, jnp.broadcast_to(rows, bi.shape)
+                        else:
+                            x, y = page_x[rows, bi], page_y[rows, bi]
+                        sw, sv = sw_t[slots_pad], sv_t[slots_pad]
+                        if p_pad > p:
+                            valid_row = (jnp.arange(p_pad) < p).astype(sv.dtype)
+                            sv = sv * valid_row[:, None]
+                        mu = prox_t[slots_pad]
+                        _, flat, losses = train_sharded(
+                            params_t, x, y, sw, sv, mask_t, freeze_t, mu
+                        )
+                if mesh is not None:
+                    # the ONE pad-then-all-to-all reshard to the (P, D_pad)
+                    # D-sharded round-buffer layout
+                    with jax.named_scope("fl/reshard"):
+                        flat = trainer.reshard_rows_traced(flat, p)
+                        losses, sv = losses[:p], sv[:p]
 
                 # --- device-resident update transform (compression) -------------
                 if transform is not None:
-                    flat = transform(t, ids, flat)
+                    with jax.named_scope("fl/transform"):
+                        flat = transform(t, ids, flat)
 
                 if async_plan is None:
                     abuf_new = abuf
                     tau_hist = None
 
                     # --- Eq. 4 aggregation from the flat buffer -----------------
-                    total = jnp.sum(sel_sizes)
-                    weights = jnp.where(total > 0.0, sel_sizes / total, 1.0 / p)
-                    if mesh is None:
-                        w_new = w + weights @ flat
-                    else:
-                        w_new = sharded_aggregate(w, flat, weights, mesh, axes)
+                    with jax.named_scope("fl/aggregate"):
+                        total = jnp.sum(sel_sizes)
+                        weights = jnp.where(total > 0.0, sel_sizes / total, 1.0 / p)
+                        if mesh is None:
+                            w_new = w + weights @ flat
+                        else:
+                            w_new = sharded_aggregate(w, flat, weights, mesh, axes)
 
                     # --- strategy bookkeeping + stop (Alg. 1/3 for FLrce) -------
                     if program.post_round is not None:
@@ -381,50 +401,64 @@ class _ChunkRunner:
                     # max_staleness=0 (B=1) the cohort is written and lands in
                     # the same round, and every op below reproduces the
                     # synchronous branch bitwise.
-                    s_max = async_plan.max_staleness
-                    b_depth = async_plan.depth
-                    k_slot = jnp.mod(t, b_depth)
-                    delays = async_plan.delays(t, ids)
-                    t32 = t.astype(jnp.int32)
-                    abuf = {
-                        "u": abuf["u"].at[k_slot].set(flat),
-                        "sizes": abuf["sizes"].at[k_slot].set(sel_sizes),
-                        "ids": abuf["ids"].at[k_slot].set(ids),
-                        "depart": abuf["depart"].at[k_slot].set(
-                            jnp.broadcast_to(t32, (p,))
-                        ),
-                        "land": abuf["land"].at[k_slot].set(t32 + delays),
-                        "valid": abuf["valid"].at[k_slot].set(
-                            jnp.ones((p,), bool)
-                        ),
-                        "anchor": abuf["anchor"].at[k_slot].set(w),
-                    }
-                    buf_u = abuf["u"].reshape(b_depth * p, -1)
-                    buf_sizes = abuf["sizes"].reshape(-1)
-                    buf_ids = abuf["ids"].reshape(-1)
-                    buf_depart = abuf["depart"].reshape(-1)
-                    buf_valid = abuf["valid"].reshape(-1)
-                    arrived = jnp.logical_and(
-                        buf_valid, abuf["land"].reshape(-1) == t32
-                    )
-                    tau = jnp.clip(staleness_of(buf_depart, t32), 0, s_max)
-                    dw = async_plan.decay_table[tau]
+                    with jax.named_scope("fl/aggregate"):
+                        s_max = async_plan.max_staleness
+                        b_depth = async_plan.depth
+                        k_slot = jnp.mod(t, b_depth)
+                        delays = async_plan.delays(t, ids)
+                        t32 = t.astype(jnp.int32)
+                        abuf = {
+                            "u": abuf["u"].at[k_slot].set(flat),
+                            "sizes": abuf["sizes"].at[k_slot].set(sel_sizes),
+                            "ids": abuf["ids"].at[k_slot].set(ids),
+                            "depart": abuf["depart"].at[k_slot].set(
+                                jnp.broadcast_to(t32, (p,))
+                            ),
+                            "land": abuf["land"].at[k_slot].set(t32 + delays),
+                            "valid": abuf["valid"].at[k_slot].set(
+                                jnp.ones((p,), bool)
+                            ),
+                            "anchor": abuf["anchor"].at[k_slot].set(w),
+                        }
+                        buf_u = abuf["u"].reshape(b_depth * p, -1)
+                        buf_sizes = abuf["sizes"].reshape(-1)
+                        buf_ids = abuf["ids"].reshape(-1)
+                        buf_depart = abuf["depart"].reshape(-1)
+                        buf_valid = abuf["valid"].reshape(-1)
+                        arrived = jnp.logical_and(
+                            buf_valid, abuf["land"].reshape(-1) == t32
+                        )
+                        tau = jnp.clip(staleness_of(buf_depart, t32), 0, s_max)
+                        dw = async_plan.decay_table[tau]
 
-                    # --- staleness-weighted Eq. 4 over this round's arrivals ----
-                    # (weight n_k · decay(τ_k), renormalized; an arrival-free
-                    # round leaves w unchanged: all-zero weights)
-                    scaled = jnp.where(arrived, buf_sizes * dw, 0.0)
-                    total = jnp.sum(scaled)
-                    n_arr = jnp.sum(arrived.astype(jnp.float32))
-                    weights = jnp.where(
-                        total > 0.0,
-                        scaled / total,
-                        jnp.where(arrived, 1.0 / jnp.maximum(n_arr, 1.0), 0.0),
-                    )
-                    if mesh is None:
-                        w_new = w + weights @ buf_u
-                    else:
-                        w_new = sharded_aggregate(w, buf_u, weights, mesh, axes)
+                        # --- staleness-weighted Eq. 4 over this round's arrivals
+                        # (weight n_k · decay(τ_k), renormalized; an arrival-free
+                        # round leaves w unchanged: all-zero weights)
+                        scaled = jnp.where(arrived, buf_sizes * dw, 0.0)
+                        total = jnp.sum(scaled)
+                        n_arr = jnp.sum(arrived.astype(jnp.float32))
+                        weights = jnp.where(
+                            total > 0.0,
+                            scaled / total,
+                            jnp.where(arrived, 1.0 / jnp.maximum(n_arr, 1.0), 0.0),
+                        )
+                        if mesh is None:
+                            w_new = w + weights @ buf_u
+                        else:
+                            w_new = sharded_aggregate(w, buf_u, weights, mesh, axes)
+
+                        # landed rows leave the buffer; the rest stay pending
+                        abuf_new = {
+                            **abuf,
+                            "valid": jnp.logical_and(
+                                buf_valid, jnp.logical_not(arrived)
+                            ).reshape(b_depth, p),
+                        }
+                        tau_hist = (
+                            jnp.zeros((b_depth,), jnp.int32)
+                            .at[tau]
+                            .add(arrived.astype(jnp.int32))
+                        )
 
                     # --- strategy bookkeeping over the arrivals -----------------
                     if program.post_round_async is not None:
@@ -436,38 +470,34 @@ class _ChunkRunner:
                     else:
                         stop = jnp.asarray(False)
 
-                    # landed rows leave the buffer; the rest stay pending
-                    abuf_new = {
-                        **abuf,
-                        "valid": jnp.logical_and(
-                            buf_valid, jnp.logical_not(arrived)
-                        ).reshape(b_depth, p),
-                    }
-                    tau_hist = (
-                        jnp.zeros((b_depth,), jnp.int32)
-                        .at[tau]
-                        .add(arrived.astype(jnp.int32))
-                    )
-
                 # --- per-round stats (device nanmean over clients) --------------
-                cnt = jnp.sum(sv, axis=1)
-                has = cnt > 0.0
-                mean_k = jnp.where(has, jnp.sum(losses * sv, axis=1) / jnp.maximum(cnt, 1.0), 0.0)
-                n_has = jnp.sum(has.astype(jnp.float32))
-                mean_loss = jnp.where(
-                    n_has > 0.0, jnp.sum(mean_k) / jnp.maximum(n_has, 1.0), jnp.nan
-                )
+                # and the round's real local steps (the rest of the P × S
+                # bucket the cohort ran is padding)
+                with jax.named_scope("fl/round_stats"):
+                    cnt = jnp.sum(sv, axis=1)
+                    has = cnt > 0.0
+                    mean_k = jnp.where(
+                        has, jnp.sum(losses * sv, axis=1) / jnp.maximum(cnt, 1.0), 0.0
+                    )
+                    n_has = jnp.sum(has.astype(jnp.float32))
+                    mean_loss = jnp.where(
+                        n_has > 0.0, jnp.sum(mean_k) / jnp.maximum(n_has, 1.0), jnp.nan
+                    )
+                    useful_steps = jnp.sum(cnt).astype(jnp.int32)
 
                 # --- evaluation (only when the loop driver would) ---------------
-                evaluated = jnp.logical_or(
-                    jnp.logical_or(t % eval_every == 0, stop), t == max_rounds - 1
-                )
-                acc = jax.lax.cond(
-                    evaluated,
-                    lambda wv: model.accuracy(eval_params(wv), eval_x, eval_y).astype(jnp.float32),
-                    lambda wv: last_acc,
-                    w_new,
-                )
+                with jax.named_scope("fl/eval"):
+                    evaluated = jnp.logical_or(
+                        jnp.logical_or(t % eval_every == 0, stop), t == max_rounds - 1
+                    )
+                    acc = jax.lax.cond(
+                        evaluated,
+                        lambda wv: model.accuracy(
+                            eval_params(wv), eval_x, eval_y
+                        ).astype(jnp.float32),
+                        lambda wv: last_acc,
+                        w_new,
+                    )
 
                 # rounds after a stop still execute (scan has no early exit) but
                 # never touch the carry: the final state is the stop round's.
@@ -485,6 +515,7 @@ class _ChunkRunner:
                     "acc": acc,
                     "evaluated": evaluated,
                     "mean_loss": mean_loss,
+                    "useful_steps": useful_steps,
                     "valid": jnp.logical_not(stopped),
                 }
                 if tau_hist is not None:
@@ -564,6 +595,7 @@ class _ChunkPlan:
     xs: Tuple                     # the scan's stacked per-round inputs
     use_prox: bool
     has_mask: bool
+    steps: int                    # S, the chunk's step bucket
     sched_bytes: int              # host bytes of this chunk's schedules
     page_bytes: int               # H2D bytes of this chunk's page (paged only)
 
@@ -648,16 +680,52 @@ def run_scan_driver(
                 "(FLrce withholds the async hook under sketched V/A maps)"
             )
 
+    stats: Dict[str, Any] = {
+        "driver": "scan",
+        "pipeline": bool(pipeline),
+        "store": "paged" if paged else "resident",
+        "chunks": 0,
+        "speculative_chunks": 0,
+        "cancelled_chunks": 0,
+        "setup_store_s": 0.0,
+        "host_build_s": 0.0,
+        "dispatch_s": 0.0,
+        "device_wait_s": 0.0,
+        "host_flush_s": 0.0,
+        "compile_s": 0.0,
+        "total_s": 0.0,
+        "steps_useful": 0,
+        "steps_executed": 0,
+        "schedule_bytes_host": 0,
+        "page_bytes_h2d": 0,
+        "peak_live_bytes": 0,
+    }
+
+    @contextlib.contextmanager
+    def span(name: str, key: Optional[str] = None, chunk: Optional[int] = None):
+        """One host span: a profiler ``TraceAnnotation`` (which records
+        nothing while no trace runs), its seconds added to ``stats[key]``.
+        ``chunk`` (the chunk's first round, shared by every span of one
+        chunk) rides on the trace event as a stat."""
+        t = time.perf_counter()
+        meta = {} if chunk is None else {"chunk": chunk}
+        with jax.profiler.TraceAnnotation(name, **meta):
+            yield
+        if key is not None:
+            stats[key] += time.perf_counter() - t
+
     params = init_params if init_params is not None else model.init(jax.random.PRNGKey(seed))
     n_params = param_count(params)
     w, unflatten = flatten_pytree(params)
-    if paged:
-        # fleet-scale layout: the (M, N_max, …) universe stays HOST-side;
-        # chunks page their candidate rows on demand (O(P_cand) device memory)
-        store = HostClientStore.from_dataset(dataset)
-    else:
-        # with a mesh the store is placed data-axis-sharded in ONE transfer
-        store = DeviceClientStore.from_dataset(dataset, mesh=mesh)
+    with span("fl.setup.store", "setup_store_s"):
+        if paged:
+            # fleet-scale layout: the (M, N_max, …) universe stays HOST-side;
+            # chunks page their candidate rows on demand (O(P_cand) device memory)
+            store = HostClientStore.from_dataset(dataset)
+        else:
+            # with a mesh the store is placed data-axis-sharded in ONE transfer
+            # (enqueued here; chunk 0's dispatch waits for it)
+            store = DeviceClientStore.from_dataset(dataset, mesh=mesh)
     m = store.num_clients
     ledger = ResourceLedger(device=device)
     # the strategy's device-resident update post-processing (Fedcom top-k,
@@ -776,183 +844,188 @@ def run_scan_driver(
         r = min(chunk_rounds, max_rounds - t0)
         ts = list(range(t0, t0 + r))
 
-        # ---- candidate set (the chunk program's client index space) -------
-        if program.select is None:
-            # host-precomputed selection: the candidate set is exactly the
-            # union of the chunk's cohorts — always exact.  Bucketed to a
-            # power of two (pad = duplicated last id) so the jitted chunk
-            # keeps a stable candidate-axis shape; pad slots are unreachable
-            # because host slots only point at real candidates.
-            host_ids = np.stack(
-                [np.asarray(strategy.select(t)) for t in ts]
-            ).astype(np.int64)
-            cand = np.unique(host_ids)
-            n_bucket = _bucket_candidates(len(cand), m)
-            cand_pad = np.concatenate(
-                [cand, np.full(n_bucket - len(cand), cand[-1], np.int64)]
-            )
-            host_slots = np.searchsorted(cand, host_ids).astype(np.int32)
-            phis = np.zeros(r, np.float32)
-        else:
-            # device-side selection: the strategy proposes a candidate
-            # superset (None ⇒ full universe — the exact-equivalence mode,
-            # where slots ≡ ids bitwise).  NEVER padded: ``top_k`` over the
-            # candidate heuristic could select a duplicated pad row.
-            host_ids = None
-            proposal = strategy.propose_candidates(np.asarray(ts))
-            if proposal is None:
-                cand = np.arange(m, dtype=np.int64)
+        with span("fl.build.candidates", chunk=t0):
+            # ---- candidate set (the chunk program's client index space) ---
+            if program.select is None:
+                # host-precomputed selection: the candidate set is exactly the
+                # union of the chunk's cohorts — always exact.  Bucketed to a
+                # power of two (pad = duplicated last id) so the jitted chunk
+                # keeps a stable candidate-axis shape; pad slots are unreachable
+                # because host slots only point at real candidates.
+                host_ids = np.stack(
+                    [np.asarray(strategy.select(t)) for t in ts]
+                ).astype(np.int64)
+                cand = np.unique(host_ids)
+                n_bucket = _bucket_candidates(len(cand), m)
+                cand_pad = np.concatenate(
+                    [cand, np.full(n_bucket - len(cand), cand[-1], np.int64)]
+                )
+                host_slots = np.searchsorted(cand, host_ids).astype(np.int32)
+                phis = np.zeros(r, np.float32)
             else:
-                cand = np.asarray(proposal, np.int64)
-                if (
-                    cand.ndim != 1
-                    or len(cand) < strategy.p
-                    or len(np.unique(cand)) != len(cand)
-                    or np.any(np.diff(cand) < 0)
-                    or (len(cand) and (cand[0] < 0 or cand[-1] >= m))
+                # device-side selection: the strategy proposes a candidate
+                # superset (None ⇒ full universe — the exact-equivalence mode,
+                # where slots ≡ ids bitwise).  NEVER padded: ``top_k`` over the
+                # candidate heuristic could select a duplicated pad row.
+                host_ids = None
+                proposal = strategy.propose_candidates(np.asarray(ts))
+                if proposal is None:
+                    cand = np.arange(m, dtype=np.int64)
+                else:
+                    cand = np.asarray(proposal, np.int64)
+                    if (
+                        cand.ndim != 1
+                        or len(cand) < strategy.p
+                        or len(np.unique(cand)) != len(cand)
+                        or np.any(np.diff(cand) < 0)
+                        or (len(cand) and (cand[0] < 0 or cand[-1] >= m))
+                    ):
+                        raise ValueError(
+                            f"{strategy.name}.propose_candidates must return sorted "
+                            f"unique ids in [0, {m}) with P_cand >= P={strategy.p}; "
+                            f"got shape {cand.shape}"
+                        )
+                cand_pad = cand
+                host_slots = np.zeros((r, strategy.p), np.int32)
+                phis = program.explore_phis(np.asarray(ts))
+
+        with span("fl.build.configs", chunk=t0):
+            # per-(round, candidate) local configs: epochs/prox enter the
+            # compiled chunk; the ledger fractions are reused host-side at flush.
+            # The None template means metadata-only (no mask materialization per
+            # candidate) — client_config purity makes the forms interchangeable.
+            # O(R · P_cand) host work, not O(R · M): only candidate columns exist.
+            cfg_grid = [
+                [strategy.client_config(t, int(cid), None) for cid in cand_pad]
+                for t in ts
+            ]
+            for row in cfg_grid:
+                for cfg in row:
+                    if cfg.mask is not None:
+                        raise ValueError(
+                            f"{strategy.name} materialized a mask from "
+                            "client_config(t, cid, None); with a None template "
+                            "the config must be metadata-only"
+                        )
+            epochs = np.asarray([[cfg.epochs for cfg in row] for row in cfg_grid], np.int32)
+            prox = np.asarray([[cfg.prox_mu for cfg in row] for row in cfg_grid], np.float32)
+            use_prox = bool(np.any(prox > 0.0))
+            # both the mesh chunks and device-side selection forbid per-cohort
+            # variants — one O(R·P_cand) sweep establishes the invariant for
+            # either (cheap for a compliant strategy: its configs are
+            # metadata-only, and misuse costs an error, not silence)
+            if mesh is not None or program.select is not None:
+                if any(
+                    cfg.freeze_frac for row in cfg_grid for cfg in row
+                ) or any(
+                    strategy.client_config(t, int(cid), params).mask is not None
+                    for t in ts for cid in cand
                 ):
                     raise ValueError(
-                        f"{strategy.name}.propose_candidates must return sorted "
-                        f"unique ids in [0, {m}) with P_cand >= P={strategy.p}; "
-                        f"got shape {cand.shape}"
+                        f"{strategy.name} uses per-client masks or freeze flags; "
+                        + ("the mesh-sharded chunks need metadata-only configs "
+                           "(supports_sharded_scan must be False)"
+                           if mesh is not None else
+                           "with device-side selection they cannot be precomputed "
+                           "for the selected cohort (host-precomputable selection "
+                           "is required)")
                     )
-            cand_pad = cand
-            host_slots = np.zeros((r, strategy.p), np.int32)
-            phis = program.explore_phis(np.asarray(ts))
-        n_cand = len(cand_pad)
-
-        # per-(round, candidate) local configs: epochs/prox enter the
-        # compiled chunk; the ledger fractions are reused host-side at flush.
-        # The None template means metadata-only (no mask materialization per
-        # candidate) — client_config purity makes the forms interchangeable.
-        # O(R · P_cand) host work, not O(R · M): only candidate columns exist.
-        cfg_grid = [
-            [strategy.client_config(t, int(cid), None) for cid in cand_pad]
-            for t in ts
-        ]
-        for row in cfg_grid:
-            for cfg in row:
-                if cfg.mask is not None:
-                    raise ValueError(
-                        f"{strategy.name} materialized a mask from "
-                        "client_config(t, cid, None); with a None template "
-                        "the config must be metadata-only"
-                    )
-        epochs = np.asarray([[cfg.epochs for cfg in row] for row in cfg_grid], np.int32)
-        prox = np.asarray([[cfg.prox_mu for cfg in row] for row in cfg_grid], np.float32)
-        use_prox = bool(np.any(prox > 0.0))
-        # both the mesh chunks and device-side selection forbid per-cohort
-        # variants — one O(R·P_cand) sweep establishes the invariant for
-        # either (cheap for a compliant strategy: its configs are
-        # metadata-only, and misuse costs an error, not silence)
-        if mesh is not None or program.select is not None:
-            if any(
-                cfg.freeze_frac for row in cfg_grid for cfg in row
-            ) or any(
-                strategy.client_config(t, int(cid), params).mask is not None
-                for t in ts for cid in cand
-            ):
-                raise ValueError(
-                    f"{strategy.name} uses per-client masks or freeze flags; "
-                    + ("the mesh-sharded chunks need metadata-only configs "
-                       "(supports_sharded_scan must be False)"
-                       if mesh is not None else
-                       "with device-side selection they cannot be precomputed "
-                       "for the selected cohort (host-precomputable selection "
-                       "is required)")
-                )
 
         # batch schedules from the SAME fold-in streams the loop engines use;
         # per-candidate columns (client_ids) keep host bytes O(P_cand), and
         # the memo keys by GLOBAL id so dense and compact builds share hits
-        sched = build_chunk_schedule(
-            store.sizes_host[cand_pad], epochs, batch_size, t0,
-            lambda t, cid: client_batch_rng(seed, t, cid),
-            cache_key=seed, client_ids=cand_pad,
-        )
-        if program.select is None:
-            # the selected cohorts are known, so per-round masks (Dropout)
-            # and per-leaf freeze flags (TimelyFL) are materialized host-side
-            # — pure re-invocation with the shape template — and ride into
-            # the scan as stacked (R, P, ...) inputs.  The mesh chunks take
-            # neither (validated above): their variant inputs are all-pass.
-            if mesh is not None:
+        with span("fl.build.schedule", chunk=t0):
+            sched = build_chunk_schedule(
+                store.sizes_host[cand_pad], epochs, batch_size, t0,
+                lambda t, cid: client_batch_rng(seed, t, cid),
+                cache_key=seed, client_ids=cand_pad,
+            )
+        with span("fl.build.variants", chunk=t0):
+            if program.select is None:
+                # the selected cohorts are known, so per-round masks (Dropout)
+                # and per-leaf freeze flags (TimelyFL) are materialized host-side
+                # — pure re-invocation with the shape template — and ride into
+                # the scan as stacked (R, P, ...) inputs.  The mesh chunks take
+                # neither (validated above): their variant inputs are all-pass.
+                if mesh is not None:
+                    has_mask = False
+                    mask_xs = {}
+                    freeze_rounds = [
+                        stack_freeze_flags(params, [0.0] * runner.p_pad) for _ in ts
+                    ]
+                else:
+                    sel_cfgs = [
+                        [strategy.client_config(t, int(cid), params) for cid in host_ids[i]]
+                        for i, t in enumerate(ts)
+                    ]
+                    mask_rounds = [
+                        stack_variant_trees([c.mask for c in row], params) for row in sel_cfgs
+                    ]
+                    has_mask = any(flag for _, flag in mask_rounds)
+                    if has_mask:
+                        ones = jax.tree_util.tree_map(
+                            lambda l: jnp.ones((strategy.p,) + l.shape, l.dtype), params
+                        )
+                        mask_xs = jax.tree_util.tree_map(
+                            lambda *ls: jnp.stack(ls),
+                            *[mt if flag else ones for mt, flag in mask_rounds],
+                        )
+                    else:
+                        mask_xs = {}
+                    freeze_rounds = [
+                        stack_freeze_flags(params, [c.freeze_frac for c in row])
+                        for row in sel_cfgs
+                    ]
+            else:
+                # device-side selection: the cohort is unknown at chunk build, so
+                # per-round host-built variants cannot be gathered for it (no
+                # masks/freeze — established by the shared sweep above)
                 has_mask = False
                 mask_xs = {}
                 freeze_rounds = [
                     stack_freeze_flags(params, [0.0] * runner.p_pad) for _ in ts
                 ]
+            freeze_xs = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *freeze_rounds)
+
+        with span("fl.build.place", chunk=t0):
+            # fresh device buffers every chunk (double-buffered by construction):
+            # the async H2D copies for chunk k+1 — schedules, the candidate
+            # remap and (paged) the page — overlap chunk k's execution and never
+            # alias tensors a running chunk still reads
+            bi_xs, sw_xs, sv_xs = place_schedule(sched, mesh)
+            # every other chunk input is pinned to an explicit replicated
+            # placement: an unpinned single-device array would be resharded by
+            # every mesh dispatch through jitted slice helpers — a per-chunk
+            # recompile the engine's compile sentinel rejects
+            if mesh is None:
+                put = jax.device_put
             else:
-                sel_cfgs = [
-                    [strategy.client_config(t, int(cid), params) for cid in host_ids[i]]
-                    for i, t in enumerate(ts)
-                ]
-                mask_rounds = [
-                    stack_variant_trees([c.mask for c in row], params) for row in sel_cfgs
-                ]
-                has_mask = any(flag for _, flag in mask_rounds)
-                if has_mask:
-                    ones = jax.tree_util.tree_map(
-                        lambda l: jnp.ones((strategy.p,) + l.shape, l.dtype), params
-                    )
-                    mask_xs = jax.tree_util.tree_map(
-                        lambda *ls: jnp.stack(ls),
-                        *[mt if flag else ones for mt, flag in mask_rounds],
-                    )
-                else:
-                    mask_xs = {}
-                freeze_rounds = [
-                    stack_freeze_flags(params, [c.freeze_frac for c in row])
-                    for row in sel_cfgs
-                ]
-        else:
-            # device-side selection: the cohort is unknown at chunk build, so
-            # per-round host-built variants cannot be gathered for it (no
-            # masks/freeze — established by the shared sweep above)
-            has_mask = False
-            mask_xs = {}
-            freeze_rounds = [
-                stack_freeze_flags(params, [0.0] * runner.p_pad) for _ in ts
-            ]
-        freeze_xs = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *freeze_rounds)
+                from jax.sharding import NamedSharding, PartitionSpec
 
-        # fresh device buffers every chunk (double-buffered by construction):
-        # the async H2D copies for chunk k+1 — schedules, the candidate
-        # remap and (paged) the page — overlap chunk k's execution and never
-        # alias tensors a running chunk still reads
-        bi_xs, sw_xs, sv_xs = place_schedule(sched, mesh)
-        # every other chunk input is pinned to an explicit replicated
-        # placement: an unpinned single-device array would be resharded by
-        # every mesh dispatch through jitted slice helpers — a per-chunk
-        # recompile the engine's compile sentinel rejects
-        if mesh is None:
-            put = jax.device_put
-        else:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            rep = NamedSharding(mesh, PartitionSpec())
-            put = lambda a: jax.device_put(a, rep)
-        cand_dev = put(cand_pad.astype(np.int32))
-        page = None
-        page_bytes = 0
-        if paged:
-            pstore = store.page(cand_pad, mesh=mesh)
-            page = (pstore.x, pstore.y, pstore.sizes.astype(jnp.float32))
-            page_bytes = int(pstore.x.nbytes) + int(pstore.y.nbytes)
-        xs = (
-            put(np.arange(t0, t0 + r, dtype=np.int32)),
-            put(np.asarray(phis)),
-            put(np.asarray(host_slots)),
-            bi_xs,
-            sw_xs,
-            sv_xs,
-            put(np.asarray(prox)),
-            mask_xs,
-            jax.tree_util.tree_map(put, freeze_xs),
-        )
+                rep = NamedSharding(mesh, PartitionSpec())
+                put = lambda a: jax.device_put(a, rep)
+            cand_dev = put(cand_pad.astype(np.int32))
+            page = None
+            page_bytes = 0
+            if paged:
+                pstore = store.page(cand_pad, mesh=mesh)
+                page = (pstore.x, pstore.y, pstore.sizes.astype(jnp.float32))
+                page_bytes = int(pstore.x.nbytes) + int(pstore.y.nbytes)
+            xs = (
+                put(np.arange(t0, t0 + r, dtype=np.int32)),
+                put(np.asarray(phis)),
+                put(np.asarray(host_slots)),
+                bi_xs,
+                sw_xs,
+                sv_xs,
+                put(np.asarray(prox)),
+                mask_xs,
+                jax.tree_util.tree_map(put, freeze_xs),
+            )
         return _ChunkPlan(t0=t0, r=r, cand=cand, cand_dev=cand_dev, page=page,
                           cfg_grid=cfg_grid, xs=xs,
                           use_prox=use_prox, has_mask=has_mask,
+                          steps=sched.num_steps,
                           sched_bytes=int(sched.nbytes), page_bytes=page_bytes)
 
     records: List[RoundRecord] = []
@@ -1001,6 +1074,8 @@ def run_scan_driver(
                 evaluated=bool(outs["evaluated"][i]),
             )
             records.append(rec)
+            stats["steps_useful"] += int(outs["useful_steps"][i])
+            stats["steps_executed"] += runner.p_pad * plan.steps
             flushed += 1
             if verbose:
                 print(
@@ -1027,21 +1102,6 @@ def run_scan_driver(
     # here unread, so truncation recovers the serial driver's exact records,
     # ledger and write-back state.
     depth = 2 if pipeline else 1
-    stats: Dict[str, Any] = {
-        "driver": "scan",
-        "pipeline": bool(pipeline),
-        "store": "paged" if paged else "resident",
-        "chunks": 0,
-        "speculative_chunks": 0,
-        "cancelled_chunks": 0,
-        "host_build_s": 0.0,
-        "device_wait_s": 0.0,
-        "host_flush_s": 0.0,
-        "total_s": 0.0,
-        "schedule_bytes_host": 0,
-        "page_bytes_h2d": 0,
-        "peak_live_bytes": 0,
-    }
     if async_plan is not None:
         stats["async_max_staleness"] = async_plan.max_staleness
         stats["async_arrivals"] = 0
@@ -1056,26 +1116,27 @@ def run_scan_driver(
     # candidate shapes this is exactly 1 per (strategy, mesh, knobs) job, and
     # any drift is the silent-recompile regression the sentinel exists to
     # catch.  `compiles_total` additionally includes programs compiled
-    # outside dispatch (none today; a canary for future host-side jits).
+    # outside dispatch: eager device ops of the build and the flush (the
+    # freeze-flag stacks among them), compiled the first time they run.
+    # `compile_s` is the seconds of all of them: set-up, inside ``dispatch_s``
+    # for the chunk program and inside the other phases' timers for the rest.
     stats["compiles_chunk"] = 0
-    compile_counter = CompileCounter()
-    compile_counter.__enter__()
-    t_start = time.perf_counter()
-    flush_mark = t_start
-    try:
+    with CompileCounter() as compile_counter, span("fl.job", "total_s"):
+        flush_mark = time.perf_counter()
         while pending or (t_dispatch < max_rounds and not stopped):
             # fill the pipeline: build chunk inputs (host), place them (async
             # H2D) and dispatch (async) — never blocking on in-flight chunks
             while len(pending) < depth and t_dispatch < max_rounds and not stopped:
-                b0 = time.perf_counter()
-                plan = build_chunk(t_dispatch)
-                c0 = compile_counter.compiles
-                w, sc, abuf, es_flag, last_acc, outs = runner.run_chunk(
-                    w, sc, abuf, es_flag, last_acc, plan.cand_dev, plan.page,
-                    plan.xs, plan.use_prox, plan.has_mask,
-                )
-                stats["compiles_chunk"] += compile_counter.compiles - c0
-                stats["host_build_s"] += time.perf_counter() - b0
+                t0 = t_dispatch
+                with span("fl.build", "host_build_s", chunk=t0):
+                    plan = build_chunk(t0)
+                with span("fl.dispatch", "dispatch_s", chunk=t0), \
+                        compile_counter.delta() as compiled:
+                    w, sc, abuf, es_flag, last_acc, outs = runner.run_chunk(
+                        w, sc, abuf, es_flag, last_acc, plan.cand_dev, plan.page,
+                        plan.xs, plan.use_prox, plan.has_mask,
+                    )
+                stats["compiles_chunk"] += compiled.compiles
                 stats["schedule_bytes_host"] += plan.sched_bytes
                 stats["page_bytes_h2d"] += plan.page_bytes
                 if pending:
@@ -1084,54 +1145,49 @@ def run_scan_driver(
                 t_dispatch += plan.r
 
             plan, outs = pending.popleft()
-            w0 = time.perf_counter()
-            outs = jax.device_get(outs)            # the chunk's ONE host sync
-            stats["device_wait_s"] += time.perf_counter() - w0
+            with span("fl.wait", "device_wait_s", chunk=plan.t0):
+                outs = jax.device_get(outs)        # the chunk's ONE host sync
             # sampled when the pipeline is fullest (this chunk's buffers are
             # still live, the next chunk's page/schedules already transferred) —
             # the flat-in-M acceptance probe for the paged store
             stats["peak_live_bytes"] = max(stats["peak_live_bytes"], _live_device_bytes())
 
-            f0 = time.perf_counter()
-            flushed, chunk_stopped = flush_chunk(plan, outs)
-            if flushed:
-                any_flushed = True
-                last_exploit = bool(outs["exploited"][flushed - 1])
-                t_final = plan.t0 + flushed
-            # chunk wall: everything since the previous flush completed
-            # (schedule build + compiled chunk + flush bookkeeping — under
-            # pipelining the phases overlap, so consecutive flush-to-flush
-            # deltas are the partition of total wall time), amortized over the
-            # flushed rounds
-            now = time.perf_counter()
-            wall, flush_mark = now - flush_mark, now
-            for rec in records[-flushed:] if flushed else []:
-                rec.wall_s = wall / flushed
-            if chunk_stopped:
-                stopped = True
-                # speculative chunks past the stop ran fully masked: their carry
-                # outputs are bitwise the stop round's state, their rounds all
-                # invalid — drop the outputs unread
-                stats["cancelled_chunks"] += len(pending)
-                pending.clear()
-            stats["chunks"] += 1
-            stats["host_flush_s"] += time.perf_counter() - f0
+            with span("fl.flush", "host_flush_s", chunk=plan.t0):
+                flushed, chunk_stopped = flush_chunk(plan, outs)
+                if flushed:
+                    any_flushed = True
+                    last_exploit = bool(outs["exploited"][flushed - 1])
+                    t_final = plan.t0 + flushed
+                # chunk wall: everything since the previous flush completed
+                # (schedule build + compiled chunk + flush bookkeeping — under
+                # pipelining the phases overlap, so consecutive flush-to-flush
+                # deltas are the partition of total wall time), amortized over
+                # the flushed rounds
+                now = time.perf_counter()
+                wall, flush_mark = now - flush_mark, now
+                for rec in records[-flushed:] if flushed else []:
+                    rec.wall_s = wall / flushed
+                if chunk_stopped:
+                    stopped = True
+                    # speculative chunks past the stop ran fully masked: their
+                    # carry outputs are bitwise the stop round's state, their
+                    # rounds all invalid — drop the outputs unread
+                    stats["cancelled_chunks"] += len(pending)
+                    pending.clear()
+                stats["chunks"] += 1
             # the carry write-back waits until the carry is settled: with no
             # chunk in flight, ``sc`` is exactly the flushed state (serial mode:
             # every chunk; pipelined: the final chunk or the post-stop freeze)
             if not pending and any_flushed and program.finalize is not None:
                 program.finalize(sc, t_final, last_exploit)
-
-    finally:
-        compile_counter.__exit__()
-        stats["compiles_total"] = compile_counter.compiles
+    stats["compiles_total"] = compile_counter.compiles
+    stats["compile_s"] = compile_counter.seconds
     if async_plan is not None:
         # updates still parked in the buffer when the job ended (stop or
         # round budget): departed + charged, never landed
         stats["async_pending_at_exit"] = int(
             np.sum(np.asarray(jax.device_get(abuf["valid"])))
         )
-    stats["total_s"] = time.perf_counter() - t_start
     return finalize_result(
         strategy=strategy,
         records=records,

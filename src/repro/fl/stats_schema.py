@@ -37,10 +37,15 @@ DRIVER_STATS_SCHEMA: Dict[str, Dict[str, Tuple[type, ...]]] = {
         "chunks": _INT,
         "speculative_chunks": _INT,
         "cancelled_chunks": _INT,
+        "setup_store_s": _NUM,
         "host_build_s": _NUM,
+        "dispatch_s": _NUM,
         "device_wait_s": _NUM,
         "host_flush_s": _NUM,
+        "compile_s": _NUM,
         "total_s": _NUM,
+        "steps_useful": _INT,
+        "steps_executed": _INT,
         "schedule_bytes_host": _INT,
         "page_bytes_h2d": _INT,
         "peak_live_bytes": _INT,

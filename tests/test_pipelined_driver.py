@@ -267,6 +267,16 @@ def test_driver_stats_contract(tiny_fed):
         assert st["total_s"] > 0
         assert st["host_build_s"] >= 0 and st["device_wait_s"] >= 0
         assert st["host_flush_s"] >= 0
+        # the first chunk's dispatch compiles (FedAvg's host-drawn cohorts
+        # may land in a second candidate bucket); the compiles, the chunk's
+        # and the build's eager ops', are reported on their own, and the
+        # four phase spans are disjoint parts of the loop
+        assert st["compiles_chunk"] >= 1 and st["dispatch_s"] > 0
+        assert st["compiles_total"] >= st["compiles_chunk"]
+        assert 0 < st["compile_s"] <= st["total_s"]
+        assert (st["host_build_s"] + st["dispatch_s"] + st["device_wait_s"]
+                + st["host_flush_s"]) <= st["total_s"]
+        assert st["setup_store_s"] > 0
     assert ser.driver_stats["speculative_chunks"] == 0
     # depth-2 pipeline: every chunk after the first was dispatched while its
     # predecessor was still in flight
