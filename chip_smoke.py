@@ -174,7 +174,7 @@ def one_chip(clock):
     print("driver_stats: " + " ".join(
         f"{k}={st[k]}" for k in ("setup_store_s", "host_build_s", "dispatch_s",
                                  "compile_s", "device_wait_s", "host_flush_s",
-                                 "steps_useful", "steps_executed",
+                                 "steps_useful", "steps_executed", "steps_bucket",
                                  "schedule_bytes_host", "chunks")))
     return problems
 

@@ -5,10 +5,11 @@ Two execution paths produce the same math (see DESIGN.md §Engine):
 * :class:`ClientTrainer` — the sequential reference.  One jitted SGD step per
   (model, variant), called client-by-client and step-by-step from Python.
 * :class:`BatchedCohortTrainer` — the production path.  The whole selected
-  cohort's local training runs as ONE jitted program: ``lax.scan`` over the
-  (padded) step axis, ``vmap`` over the client axis.  A single device
-  round-trip returns the stacked update pytree, the flat (P, D) update
-  matrix, and the per-client loss traces.
+  cohort's local training runs as ONE jitted program: a loop over the
+  (padded) step axis of the SGD step ``vmap``-ed over the client axis, run
+  only to the cohort's last real step.  A single device round-trip returns
+  the stacked update pytree, the flat (P, D) update matrix, and the
+  per-client loss traces.
 
 Variants cover the baselines' local tweaks in both paths:
 
@@ -299,6 +300,15 @@ def stack_freeze_flags(params: PyTree, freeze_fracs: Sequence[float]) -> PyTree:
     return jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *flags)
 
 
+def steps_to_run(valid: jax.Array) -> jax.Array:
+    """A cohort's trip count: over its (P, S) step-validity rows, the
+    largest (index of a row's last real step + 1), as an int32 scalar; 0 for
+    a cohort of padding.  Every step from there on is padding in every row.
+    """
+    ends = jnp.where(valid > 0, jnp.arange(1, valid.shape[-1] + 1, dtype=jnp.int32), 0)
+    return jnp.max(ends, initial=0)
+
+
 class BatchedCohortTrainer:
     """Runs all P selected clients' local epochs as one device program.
 
@@ -324,10 +334,17 @@ class BatchedCohortTrainer:
         )
 
     def _make_train(self, load=None):
-        """The cohort program: vmap over clients of a ``lax.scan`` over steps.
+        """The cohort program: a loop over steps of the client-vmapped SGD
+        step, run only to the cohort's last real step.
 
-        ``load`` maps one step's scanned ``(xs, ys)`` entries to that step's
-        ``(x, y)`` batch; ``None`` scans materialized batches.  The compiled
+        The trip count is :func:`steps_to_run` of the (P, S) step validity:
+        the steps after it are padding in every row (the bucket's slack),
+        and each would be an exact no-op, so they are not run.  A row that
+        ends sooner is gated by its validity, as before; the loss trace
+        keeps its (P, S) shape and reads 0 at the steps not run.
+
+        ``load`` maps one step's ``(xs, ys)`` entries to that step's
+        ``(x, y)`` batch; ``None`` takes materialized batches.  The compiled
         chunks use it to restore the store's flat samples to the model's
         feature shape and, where the store is not split over devices, to
         gather each step's batch from it (scan_driver).
@@ -339,48 +356,67 @@ class BatchedCohortTrainer:
             # vmap re-batches it, matching the sequential batched compute.
             return jax.vmap(lambda xi, yi: model.loss(p, xi[None], yi[None]))(x, y)
 
-        def one_client(global_params, xs, ys, ws, valid, mask, freeze, prox_mu, *, use_prox, has_mask):
-            def step(params, inp):
-                x, y, w, v = inp
-                if load is not None:
-                    x, y = load(x, y)
+        def step(params, global_params, x, y, w, v, mask, freeze, prox_mu, *, use_prox, has_mask):
+            if load is not None:
+                x, y = load(x, y)
 
-                def loss_fn(p):
-                    q = jax.tree_util.tree_map(lambda a, m: a * m, p, mask) if has_mask else p
-                    per = per_example_losses(q, x, y)
-                    base = jnp.sum(per * w) / jnp.maximum(jnp.sum(w), 1.0)
-                    if use_prox:
-                        # on the MASKED params, matching ClientTrainer's loss_fn
-                        sq = sum(
-                            jnp.sum(jnp.square(a - b))
-                            for a, b in zip(
-                                jax.tree_util.tree_leaves(q),
-                                jax.tree_util.tree_leaves(global_params),
-                            )
+            def loss_fn(p):
+                q = jax.tree_util.tree_map(lambda a, m: a * m, p, mask) if has_mask else p
+                per = per_example_losses(q, x, y)
+                base = jnp.sum(per * w) / jnp.maximum(jnp.sum(w), 1.0)
+                if use_prox:
+                    # on the MASKED params, matching ClientTrainer's loss_fn
+                    sq = sum(
+                        jnp.sum(jnp.square(a - b))
+                        for a, b in zip(
+                            jax.tree_util.tree_leaves(q),
+                            jax.tree_util.tree_leaves(global_params),
                         )
-                        base = base + 0.5 * prox_mu * sq
-                    return base
+                    )
+                    base = base + 0.5 * prox_mu * sq
+                return base
 
-                loss, grads = jax.value_and_grad(loss_fn)(params)
-                if has_mask:
-                    grads = jax.tree_util.tree_map(lambda g, m: g * m, grads, mask)
-                # freeze flags and the step-validity flag both gate the update
-                grads = jax.tree_util.tree_map(lambda g, f: g * (f * v), grads, freeze)
-                new_params = jax.tree_util.tree_map(lambda a, g: a - lr * g, params, grads)
-                return new_params, loss
-
-            final, losses = jax.lax.scan(step, global_params, (xs, ys, ws, valid))
-            update = tree_sub(final, global_params)
+            loss, grads = jax.value_and_grad(loss_fn)(params)
             if has_mask:
-                update = jax.tree_util.tree_map(lambda u, m: u * m, update, mask)
-            return update, losses
+                grads = jax.tree_util.tree_map(lambda g, m: g * m, grads, mask)
+            # freeze flags and the step-validity flag both gate the update
+            grads = jax.tree_util.tree_map(lambda g, f: g * (f * v), grads, freeze)
+            new_params = jax.tree_util.tree_map(lambda a, g: a - lr * g, params, grads)
+            return new_params, loss
 
         def train(global_params, xs, ys, ws, valid, mask, freeze, prox_mu, *, use_prox, has_mask):
-            updates, losses = jax.vmap(
-                functools.partial(one_client, use_prox=use_prox, has_mask=has_mask),
-                in_axes=(None, 0, 0, 0, 0, 0 if has_mask else None, 0, 0),
-            )(global_params, xs, ys, ws, valid, mask, freeze, prox_mu)
-            p = xs.shape[0]
+            p, s = valid.shape
+            cohort_step = jax.vmap(
+                functools.partial(step, use_prox=use_prox, has_mask=has_mask),
+                in_axes=(0, None, 0, 0, 0, 0, 0 if has_mask else None, 0, 0),
+            )
+
+            def body(i, carry):
+                params, losses = carry
+                x, y, w, v = (
+                    jax.lax.dynamic_index_in_dim(a, i, axis=1, keepdims=False)
+                    for a in (xs, ys, ws, valid)
+                )
+                params, loss = cohort_step(
+                    params, global_params, x, y, w, v, mask, freeze, prox_mu
+                )
+                losses = jax.lax.dynamic_update_index_in_dim(
+                    losses, loss.astype(losses.dtype), i, axis=1
+                )
+                return params, losses
+
+            start = (
+                jax.tree_util.tree_map(
+                    lambda a: jnp.broadcast_to(a, (p, *a.shape)), global_params
+                ),
+                # the loss trace takes the validity's dtype (f32), so the
+                # donated validity buffer still aliases it
+                jnp.zeros((p, s), valid.dtype),
+            )
+            final, losses = jax.lax.fori_loop(0, steps_to_run(valid), body, start)
+            updates = tree_sub(final, global_params)
+            if has_mask:
+                updates = jax.tree_util.tree_map(lambda u, m: u * m, updates, mask)
             flat = jnp.concatenate(
                 [jnp.reshape(l, (p, -1)).astype(jnp.float32)
                  for l in jax.tree_util.tree_leaves(updates)],
@@ -424,9 +460,10 @@ class BatchedCohortTrainer:
 class ShardedCohortTrainer(BatchedCohortTrainer):
     """BatchedCohortTrainer distributed over a ``(data, model)`` mesh.
 
-    Local training shard_maps the SAME vmap/scan cohort program over the mesh
+    Local training shard_maps the SAME cohort program over the mesh
     ``data`` axis — each shard trains its slice of the (client-padded) cohort
-    against the replicated global model — and the resulting flat update
+    against the replicated global model, to its own rows' last real step
+    (the step loop holds no collective) — and the resulting flat update
     matrix is resharded in one jitted step so D is split over EVERY mesh axis
     (zero-padded to the shard count), exactly the layout the sharded Gram
     reductions (aggregation, ingest, early stopping) consume.  The (P, D)
@@ -509,11 +546,13 @@ class ShardedCohortTrainer(BatchedCohortTrainer):
 
         ``with_sharding_constraint`` pins every param leaf to the sharding
         policy's layout and the (client-padded) plan tensors client-sharded
-        over ``data``; XLA partitions the vmap/scan cohort program across the
-        composed (data, model) mesh.  The flat update matrix leaves in the
-        shard_map path's row-sharded layout, so :meth:`reshard_rows_traced`
-        and everything downstream are shared verbatim with the replicated
-        path.
+        over ``data``; XLA partitions the cohort program across the
+        composed (data, model) mesh.  The trip count is read from the whole
+        cohort's validity, so every device runs the same steps and the
+        collectives inside a step stay matched.  The flat update matrix
+        leaves in the shard_map path's row-sharded layout, so
+        :meth:`reshard_rows_traced` and everything downstream are shared
+        verbatim with the replicated path.
         """
         from jax.sharding import NamedSharding, PartitionSpec as P
 

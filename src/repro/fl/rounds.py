@@ -9,11 +9,11 @@ Three interchangeable execution engines (see DESIGN.md §Engine):
 * ``engine="sequential"`` — the reference path: one jitted SGD step per
   client per batch, driven from Python.  O(P × steps) device dispatches.
 * ``engine="batched"`` — the single-device production path (default): the
-  whole cohort's local training is one jitted vmap/scan program, and the
-  round's flat (P, D) update matrix is produced on device and shared —
-  without bouncing through NumPy — between aggregation (Eq. 4),
-  relationship modeling (Eq. 5/6 via the Gram kernels), and early stopping
-  (Alg. 3).
+  whole cohort's local training is one jitted program (a step loop over the
+  client-vmapped SGD step), and the round's flat (P, D) update matrix is
+  produced on device and shared — without bouncing through NumPy — between
+  aggregation (Eq. 4), relationship modeling (Eq. 5/6 via the Gram
+  kernels), and early stopping (Alg. 3).
 * ``engine="sharded"`` — the batched program shard_mapped over a
   ``(data, model)`` mesh: cohort training splits over the ``data`` axis and
   the flat (P, D) buffer stays D-sharded over every mesh axis through

@@ -81,9 +81,12 @@ with the chunk's first round as its ``chunk`` stat, whose seconds also
 feed ``driver_stats``; with no trace running it records nothing.  The
 scan body's phases run under ``jax.named_scope("fl/<phase>")``, which
 reaches each device op's ``tf_op`` in a trace and changes no op.  Each
-round outputs its cohort's real local steps; the flush sums them into
-``driver_stats["steps_useful"]`` beside ``steps_executed`` (rounds ×
-padded cohort × step bucket).
+round outputs its cohort's real local steps and its step loop's trip
+count (the cohort's last real step); the flush sums them into
+``driver_stats["steps_useful"]`` and ``steps_executed`` (padded cohort ×
+trip count, over the rounds), beside ``steps_bucket`` (padded cohort ×
+step bucket S, over the rounds: what a loop run to the bucket's end would
+execute).
 
 Strategies opt in via ``Strategy.supports_scan`` / ``scan_program()`` — FLrce
 and every §4.1 baseline except PyramidFL, whose loss-driven selection/epoch
@@ -121,6 +124,7 @@ from repro.fl.client import (
     client_batch_rng,
     stack_freeze_flags,
     stack_variant_trees,
+    steps_to_run,
 )
 from repro.fl.metrics import ResourceLedger
 from repro.fl.strategy import Strategy
@@ -471,8 +475,10 @@ class _ChunkRunner:
                         stop = jnp.asarray(False)
 
                 # --- per-round stats (device nanmean over clients) --------------
-                # and the round's real local steps (the rest of the P × S
-                # bucket the cohort ran is padding)
+                # and the round's real local steps, and the steps its cohort
+                # ran (the trip count of its step loop, on a ``data`` axis
+                # over 1 the longest shard's; the rest of the S-step bucket
+                # was not run)
                 with jax.named_scope("fl/round_stats"):
                     cnt = jnp.sum(sv, axis=1)
                     has = cnt > 0.0
@@ -484,6 +490,7 @@ class _ChunkRunner:
                         n_has > 0.0, jnp.sum(mean_k) / jnp.maximum(n_has, 1.0), jnp.nan
                     )
                     useful_steps = jnp.sum(cnt).astype(jnp.int32)
+                    steps_run = steps_to_run(sv)
 
                 # --- evaluation (only when the loop driver would) ---------------
                 with jax.named_scope("fl/eval"):
@@ -516,6 +523,7 @@ class _ChunkRunner:
                     "evaluated": evaluated,
                     "mean_loss": mean_loss,
                     "useful_steps": useful_steps,
+                    "steps_run": steps_run,
                     "valid": jnp.logical_not(stopped),
                 }
                 if tau_hist is not None:
@@ -696,6 +704,7 @@ def run_scan_driver(
         "total_s": 0.0,
         "steps_useful": 0,
         "steps_executed": 0,
+        "steps_bucket": 0,
         "schedule_bytes_host": 0,
         "page_bytes_h2d": 0,
         "peak_live_bytes": 0,
@@ -1075,7 +1084,8 @@ def run_scan_driver(
             )
             records.append(rec)
             stats["steps_useful"] += int(outs["useful_steps"][i])
-            stats["steps_executed"] += runner.p_pad * plan.steps
+            stats["steps_executed"] += runner.p_pad * int(outs["steps_run"][i])
+            stats["steps_bucket"] += runner.p_pad * plan.steps
             flushed += 1
             if verbose:
                 print(

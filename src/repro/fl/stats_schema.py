@@ -46,6 +46,7 @@ DRIVER_STATS_SCHEMA: Dict[str, Dict[str, Tuple[type, ...]]] = {
         "total_s": _NUM,
         "steps_useful": _INT,
         "steps_executed": _INT,
+        "steps_bucket": _INT,
         "schedule_bytes_host": _INT,
         "page_bytes_h2d": _INT,
         "peak_live_bytes": _INT,

@@ -7,7 +7,9 @@ the same span's seconds go into ``driver_stats``.  Phase scopes: the
 compiled chunk's ops carry ``fl/<phase>`` in their ``op_name`` metadata
 (the device op's ``tf_op`` in a profiler trace).  Step counters:
 ``steps_useful`` is the real local steps of the flushed rounds' cohorts,
-``steps_executed`` the rounds × padded cohort × step bucket the chunk ran.
+``steps_executed`` the padded cohort × trip count (the cohort's last real
+step) the rounds ran, ``steps_bucket`` the rounds × padded cohort × step
+bucket.
 """
 import glob
 import json
@@ -126,20 +128,25 @@ def test_chunk_phases_in_order(traced_job):
 
 def test_step_counters(traced_job):
     """``steps_useful`` is the sum of ``step_valid`` over the selected rows
-    of the host-built schedules; ``steps_executed`` is rounds × P_pad × S."""
+    of the host-built schedules; ``steps_executed`` is Σ P_pad × the
+    largest real step count among a round's selected rows, and
+    ``steps_bucket`` rounds × P_pad × S."""
     res, _, built = traced_job
     st = res.driver_stats
     by_t0 = {t0: (cols, sched) for t0, cols, sched in built}
-    useful = executed = 0
+    useful = executed = bucket = 0
     for rec in res.records:
         t0 = rec.t - rec.t % JOB["scan_chunk_rounds"]
         cols, sched = by_t0[t0]
         slots = np.searchsorted(cols, rec.selected)
-        useful += int(sched.step_valid[rec.t - t0, slots].sum())
-        executed += 3 * sched.num_steps
+        rows = sched.step_valid[rec.t - t0, slots]
+        useful += int(rows.sum())
+        executed += 3 * int(rows.sum(axis=1).max())
+        bucket += 3 * sched.num_steps
     assert st["steps_useful"] == useful > 0
     assert st["steps_executed"] == executed
-    assert st["steps_useful"] < st["steps_executed"]
+    assert st["steps_bucket"] == bucket
+    assert st["steps_useful"] <= st["steps_executed"] <= st["steps_bucket"]
 
 
 def _scopes(hlo_text):
