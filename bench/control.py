@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Readings of the control and of planted faults, at a cell's own size.
 
-    python3 bench/control.py --workload cifar10_cnn.paper --seeds 1,2,3
+    python3 bench/control.py --workload emnist_cnn.paper --seeds 1,2,3
 
 For each seed: the reference (as ``run.py`` computes it), and in the
 program's place the control (the same reference in bfloat16) and the
@@ -41,29 +41,33 @@ def main(argv=None) -> int:
 
     import compare
     import traffic
-    from reference import Reference, leaf_sizes, make_init
+    from reference import Reference, leaf_sizes, make_weights
 
-    cfg, mix = c.cfg, c.mix
+    arch, cfg, mix = c.model, c.cfg, c.mix
     rounds = int(mix["check_rounds"])
     psi = float(mix["es_threshold"])
-    counts = traffic.fixed_counts(cfg, mix)
-    init = make_init(cfg)
+    counts = traffic.fixed_counts(arch, cfg, mix)
+    weights = make_weights(arch, cfg)
     stand_ins = {"control_bf16": ("bfloat16", None), "half_batch": (None, "half_batch")}
     seeds = [int(s) for s in args.seeds.split(",")]
     for seed in seeds:
-        drawn = run.derive_seeds(seed)
-        data = traffic.make_traffic(cfg, mix, counts, drawn["data"])
-        sizes = leaf_sizes(init(drawn["init"]))
+        drawn = run.derive_seeds(seed, mix)
+        data = traffic.make_traffic(arch, cfg, mix, counts, drawn["data"])
+        params, frozen = weights(drawn["init"], drawn["frozen"])
+        sizes = leaf_sizes(params)
         t0 = time.perf_counter()
-        want = Reference(cfg, mix).run(data, init(drawn["init"]), drawn["select"],
-                                       drawn["job"], rounds)
+        want = Reference(arch, cfg, mix).run(data, params, frozen, drawn["select"],
+                                             drawn["job"], rounds)
         t_ref = time.perf_counter() - t0
         for name, (precision, fault) in stand_ins.items():
-            got = Reference(cfg, mix, precision=precision, fault=fault).run(
-                data, init(drawn["init"]), drawn["select"], drawn["job"], rounds)
+            got = Reference(arch, cfg, mix, precision=precision, fault=fault).run(
+                data, params, frozen, drawn["select"], drawn["job"], rounds)
             nums = compare.numbers(got, want, sizes, rounds, c.limits, psi)
+            gaps = compare.client_gaps(got, want, sizes, rounds)
             print(json.dumps({"workload": args.workload, "seed": seed, "stand_in": name,
-                              "reference_s": t_ref, "numbers": nums}), flush=True)
+                              "reference_s": t_ref, "numbers": nums,
+                              "clients": [{**g, "samples": int(data.sizes[g["client"]])}
+                                          for g in gaps]}), flush=True)
     # the program, its ingest broken: a job of two chunks
     from repro.core import relationship
 

@@ -1,7 +1,9 @@
 """A plain reference of FLrce's first rounds, and the benchmark's weights.
 
 Written from the paper's equations in straightforward ``jax.numpy``; it
-imports nothing of the program.  It follows a job from its seed for a few
+imports nothing of the program.  The model's own mathematics (weights, a
+batch's loss, the count of correct answers) comes from the cell's model
+module, ``bench/models/<model>.py``.  It follows a job from its seed for a few
 rounds, one client at a time, and records what the comparison reads:
 
 * Alg. 2 selection: the explore flip, the explore draw, and in an exploit
@@ -22,8 +24,10 @@ rounds, one client at a time, and records what the comparison reads:
 * the eval accuracy of each round's aggregated model.
 
 ``precision`` None computes in float32 with every matmul and convolution
-at ``Precision.HIGHEST``; "bfloat16" (the control) keeps weights, samples
-and the relationship vectors in bfloat16.
+at ``Precision.HIGHEST``; "bfloat16" (the control) keeps the trained
+weights and the relationship vectors in bfloat16, and the model module
+computes in bfloat16 (real-valued samples are cast to it; token ids are
+not).
 ``fault`` plants one of the faults the comparison must catch: "half_batch"
 trains every step on the first half of its batch only.
 """
@@ -45,50 +49,13 @@ EPS = 1e-12
 COS_BAND = 0.01
 
 
-def init_params(cfg: Dict, key: jax.Array) -> Dict:
-    """He-normal weights and zero biases, in the layout the program's
-    ``PaperCNN`` takes (``conv1``, ``conv2``, ``fc`` list), float32."""
-    c1, c2 = (int(v) for v in cfg["conv_channels"])
-    k, ch, s = int(cfg["conv_kernel"]), int(cfg["channels"]), int(cfg["side"])
-    flat = (s // 4) * (s // 4) * c2
-    dims = [flat] + [int(cfg["fc_width"])] * (int(cfg["num_fc"]) - 1) + [int(cfg["num_classes"])]
-    keys = jax.random.split(key, 2 + len(dims) - 1)
-
-    def he(key, shape, fan_in):
-        return math.sqrt(2.0 / fan_in) * jax.random.normal(key, shape, jnp.float32)
-
-    return {
-        "conv1": {"w": he(keys[0], (k, k, ch, c1), k * k * ch), "b": jnp.zeros((c1,), jnp.float32)},
-        "conv2": {"w": he(keys[1], (k, k, c1, c2), k * k * c1), "b": jnp.zeros((c2,), jnp.float32)},
-        "fc": [
-            {"w": he(keys[2 + i], (a, b), a), "b": jnp.zeros((b,), jnp.float32)}
-            for i, (a, b) in enumerate(zip(dims[:-1], dims[1:]))
-        ],
-    }
-
-
-def make_init(cfg: Dict):
-    """One jitted call that makes every weight on the device from a seed."""
-    return jax.jit(lambda seed: init_params(cfg, jax.random.PRNGKey(seed)))
-
-
-def logits(params, x, prec):
-    h = x
-    for name in ("conv1", "conv2"):
-        h = lax.conv_general_dilated(
-            h, params[name]["w"], (1, 1), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=prec,
-        ) + params[name]["b"]
-        h = jnp.maximum(h, 0)
-        n, hh, ww, ch = h.shape
-        h = h.reshape(n, hh // 2, 2, ww // 2, 2, ch).max(axis=(2, 4))   # 2x2 max-pool
-    h = h.reshape(h.shape[0], -1)
-    fc = params["fc"]
-    for i, lyr in enumerate(fc):
-        h = jnp.dot(h, lyr["w"], precision=prec) + lyr["b"]
-        if i < len(fc) - 1:
-            h = jnp.maximum(h, 0)
-    return h.astype(jnp.float32)
+def make_weights(model, cfg: Dict):
+    """One jitted call that makes every weight on the device: the trained
+    parameters from one seed and the frozen ones (None where the model has
+    none) from another."""
+    return jax.jit(lambda init_seed, frozen_seed: (
+        model.init(cfg, jax.random.PRNGKey(init_seed)),
+        model.frozen(cfg, jax.random.PRNGKey(frozen_seed))))
 
 
 def _flat(tree) -> jax.Array:
@@ -97,32 +64,28 @@ def _flat(tree) -> jax.Array:
 
 
 class Reference:
-    def __init__(self, cfg: Dict, mix: Dict, precision: Optional[str] = None,
+    def __init__(self, model, cfg: Dict, mix: Dict, precision: Optional[str] = None,
                  fault: Optional[str] = None):
         if precision not in (None, "bfloat16"):
             raise ValueError(f"unknown precision {precision!r}")
-        self.cfg, self.mix, self.fault = cfg, mix, fault
+        self.model, self.cfg, self.mix, self.fault = model, cfg, mix, fault
         self.dtype = jnp.float32 if precision is None else jnp.bfloat16
         self.prec = lax.Precision.HIGHEST if precision is None else lax.Precision.DEFAULT
         self.lr = float(mix["learning_rate"])
         self.batch = int(mix["batch_size"])
-        self.shape = (int(cfg["side"]), int(cfg["side"]), int(cfg["channels"]))
         self._train = jax.jit(self._train_client)
         self._correct = jax.jit(self._count_correct)
 
     # -- one client's local SGD ----------------------------------------------
-    def _train_client(self, params, x_all, y_all, gidx, gw, n_steps):
-        dtype, prec, lr, shape = self.dtype, self.prec, self.lr, self.shape
+    def _train_client(self, params, frozen, x_all, y_all, gidx, gw, n_steps):
+        model, cfg, dtype, prec, lr = self.model, self.cfg, self.dtype, self.prec, self.lr
 
         def loss_fn(p, xb, yb, wb):
-            logp = jax.nn.log_softmax(logits(p, xb, prec), axis=-1)
-            per = -jnp.take_along_axis(logp, yb[:, None], axis=1)[:, 0]
-            return jnp.sum(per * wb) / jnp.maximum(jnp.sum(wb), 1.0)
+            return model.loss(cfg, p, frozen, xb, yb, wb, dtype, prec)
 
         def step(i, carry):
             p, total = carry
-            xb = x_all[gidx[i]].reshape((-1,) + shape).astype(dtype)
-            loss, g = jax.value_and_grad(loss_fn)(p, xb, y_all[gidx[i]], gw[i])
+            loss, g = jax.value_and_grad(loss_fn)(p, x_all[gidx[i]], y_all[gidx[i]], gw[i])
             p = jax.tree_util.tree_map(lambda a, d: (a - lr * d).astype(a.dtype), p, g)
             return p, total + loss
 
@@ -132,15 +95,14 @@ class Reference:
             lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), p, p0)
         return update, total / jnp.maximum(n_steps, 1).astype(jnp.float32)
 
-    def _count_correct(self, params, xb, yb):
+    def _count_correct(self, params, frozen, xb, yb):
         p = jax.tree_util.tree_map(lambda a: a.astype(self.dtype), params)
-        lg = logits(p, xb.reshape((-1,) + self.shape).astype(self.dtype), self.prec)
-        return jnp.sum((jnp.argmax(lg, axis=-1) == yb).astype(jnp.int32))
+        return self.model.correct(self.cfg, p, frozen, xb, yb, self.dtype, self.prec)
 
-    def _accuracy(self, params, ex, ey, block: int = 1000) -> float:
+    def _accuracy(self, params, frozen, ex, ey, block: int = 1000) -> float:
         correct = 0
         for s in range(0, ex.shape[0], block):
-            correct += int(self._correct(params, ex[s:s + block], ey[s:s + block]))
+            correct += int(self._correct(params, frozen, ex[s:s + block], ey[s:s + block]))
         return correct / ex.shape[0]
 
     def _schedule(self, job_seed: int, t: int, cid: int, idx: np.ndarray, s_max: int):
@@ -195,15 +157,17 @@ class Reference:
         return g / jnp.maximum(n[:, None] * n[None, :], EPS)
 
     # -- the job's first rounds ------------------------------------------------
-    def run(self, traffic, params0, sel_seed: int, job_seed: int, rounds: int) -> Dict:
+    def run(self, traffic, params0, frozen, sel_seed: int, job_seed: int, rounds: int) -> Dict:
+        """Follow the job for ``rounds`` rounds from the trained weights
+        ``params0`` and the frozen ``frozen`` (None where there are none)."""
         mix = self.mix
         m, p = int(mix["clients"]), int(mix["clients_per_round"])
         decay = float(mix["explore_decay"])
         sizes = np.asarray(traffic.sizes, np.int64)
         s_max = int(mix["local_epochs"]) * -(-int(sizes.max()) // self.batch)
-        x_all = jnp.asarray(traffic.x.reshape(traffic.x.shape[0], -1), self.dtype)
+        x_all = jnp.asarray(traffic.x.reshape(traffic.x.shape[0], -1))
         y_all = jnp.asarray(traffic.y)
-        ex = jnp.asarray(traffic.eval_x.reshape(traffic.eval_x.shape[0], -1), self.dtype)
+        ex = jnp.asarray(traffic.eval_x.reshape(traffic.eval_x.shape[0], -1))
         ey = jnp.asarray(traffic.eval_y)
         flat = jax.jit(_flat)
         aggregate = jax.jit(lambda w, wt, u: w + jnp.dot(wt, u, precision=self.prec))
@@ -245,7 +209,7 @@ class Reference:
             for cid in ids:
                 gidx, gw, n_steps = self._schedule(job_seed, t, cid,
                                                    traffic.client_indices[cid], s_max)
-                u, loss = self._train(params, x_all, y_all, jnp.asarray(gidx),
+                u, loss = self._train(params, frozen, x_all, y_all, jnp.asarray(gidx),
                                       jnp.asarray(gw), jnp.int32(n_steps))
                 upd.append(flat(u))
                 if n_steps:
@@ -290,7 +254,7 @@ class Reference:
             w = w_new
             out["models"][t + 1] = np.asarray(w)
             rec["loss"] = float(np.mean(losses)) if losses else float("nan")
-            rec["accuracy"] = self._accuracy(params, ex, ey)
+            rec["accuracy"] = self._accuracy(params, frozen, ex, ey)
             out["rounds"].append(rec)
             clock["train"] += t1 - t0
             clock["ingest"] += t2 - t1

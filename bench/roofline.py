@@ -1,5 +1,6 @@
-"""The yardstick's arithmetic: model FLOPs, kernel operations and bytes
-computed from shapes, and the chip's published peaks.
+"""The yardstick's arithmetic: kernel operations and bytes computed from
+shapes, and the chip's published peaks.  A model's FLOPs a sample are its
+module's (``bench/models/<model>.py``).
 
 Nothing here reads the program: a change to the program's own FLOP or
 roofline helpers cannot move these numbers.
@@ -8,7 +9,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Dict, List, Tuple
+from typing import Dict, Tuple
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -23,34 +24,6 @@ def peaks(device_kind: str) -> Dict:
             f"(known: {sorted(table)}); add them to bench/peaks.json"
         )
     return table[device_kind]
-
-
-def _cnn_dims(cfg: Dict) -> Tuple[int, int, int, int, List[int]]:
-    s, c = int(cfg["side"]), int(cfg["channels"])
-    c1, c2 = (int(v) for v in cfg["conv_channels"])
-    k = int(cfg["conv_kernel"])
-    flat = (s // 4) * (s // 4) * c2
-    fc = [flat] + [int(cfg["fc_width"])] * (int(cfg["num_fc"]) - 1) + [int(cfg["num_classes"])]
-    return s, c, c1, c2, k, fc
-
-
-def cnn_param_count(cfg: Dict) -> int:
-    """Weights and biases of the 2-conv + fc CNN."""
-    s, c, c1, c2, k, fc = _cnn_dims(cfg)
-    conv = (k * k * c * c1 + c1) + (k * k * c1 * c2 + c2)
-    return conv + sum(a * b + b for a, b in zip(fc[:-1], fc[1:]))
-
-
-def cnn_flops_per_sample(cfg: Dict) -> float:
-    """Model FLOPs to train on one sample: the forward pass's
-    multiply-adds (2 FLOPs each) of both SAME-padded convolutions and the
-    fc stack, times 3 for forward plus backward.  Pooling, activations,
-    biases and the loss are left out, as is any padding the program adds."""
-    s, c, c1, c2, k, fc = _cnn_dims(cfg)
-    conv1 = 2 * s * s * k * k * c * c1
-    conv2 = 2 * (s // 2) * (s // 2) * k * k * c1 * c2
-    dense = sum(2 * a * b for a, b in zip(fc[:-1], fc[1:]))
-    return 3.0 * (conv1 + conv2 + dense)
 
 
 def cross_gram_cost(k: int, m: int, d: int, itemsize: int = 4,

@@ -1,14 +1,20 @@
 #!/usr/bin/env python3
 """Run one benchmark cell once on the chip and print one result line.
 
-    python3 bench/run.py --workload cifar10_cnn.paper --seed 7 --seconds 20 --trace 0
+    python3 bench/run.py --workload emnist_cnn.paper --seed 7 --seconds 20 --trace 0
 
 A cell (``workloads`` in ``BENCHMARK.json``) names a model configuration
 (``bench/configs/<config>.json``) and a traffic mix
 (``bench/workloads/<traffic>.json``); its correctness limits are
 ``bench/limits/<cell>.json`` and each per-layer metric is read by
-``bench/metrics/<metric>.py``.  All are found by name: a new cell, config
-or metric is new files and ``BENCHMARK.json`` entries.
+``bench/metrics/<metric>.py``.  The configuration's ``model`` key names
+its model module, ``bench/models/<model>.py``, which gives all that is
+particular to the architecture: the program's model object, the trained
+and the frozen weights, the samples' features and number of classes, the
+plain reference's loss and count of correct answers, and the model FLOPs a
+sample.  All are found by name: a new cell, config, model or metric is new
+files and ``BENCHMARK.json`` entries, and no shared harness file names a
+model.
 
 One process runs one federated job through the program's public entry,
 ``run_federated(model, data, FLrce(...), engine="sharded", driver="scan")``,
@@ -69,16 +75,33 @@ def _json(path: str) -> Dict:
         return json.load(f)
 
 
+def _load_file(path: str, module_name: str):
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_model(name: str, directory: str = os.path.join(HERE, "models")):
+    """The model module ``<directory>/<name>.py`` (see ``bench/models``)."""
+    path = os.path.join(directory, f"{name}.py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no model module {path}")
+    return _load_file(path, f"bench_model_{name}")
+
+
 def load_cell(name: str) -> SimpleNamespace:
     bench = _json(os.path.join(ROOT, "BENCHMARK.json"))
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json (have {sorted(cells)})")
     cell = cells[name]
+    cfg = _json(os.path.join(HERE, "configs", f"{cell['config']}.json"))
     return SimpleNamespace(
         bench=bench,
         cell=cell,
-        cfg=_json(os.path.join(HERE, "configs", f"{cell['config']}.json")),
+        cfg=cfg,
+        model=load_model(cfg["model"]),
         mix=_json(os.path.join(HERE, "workloads", f"{cell['traffic']}.json")),
         limits=_json(os.path.join(HERE, "limits", f"{name}.json"))["limits"],
     )
@@ -88,15 +111,21 @@ def applies(metric: Dict, cell_name: str) -> bool:
     return "workloads" not in metric or cell_name in metric["workloads"]
 
 
-def derive_seeds(seed: int) -> Dict[str, int]:
-    """Independent seeds for the data, the weights, FLrce's selection stream
-    and the batch shuffles, all from ``--seed`` (any whole number)."""
+def derive_seeds(seed: int, mix: Optional[Dict] = None) -> Dict[str, int]:
+    """Independent seeds for the data, the trained weights, FLrce's selection
+    stream, the batch shuffles and the frozen weights, all from ``--seed``
+    (any whole number); the selection stream is the mix's
+    ``selection_seed`` where it has one (``bench/traffic.py``)."""
     import numpy as np
 
     ss = np.random.SeedSequence(abs(int(seed)), spawn_key=(int(seed < 0),))
-    data, init, sel, job = ss.spawn(4)
+    data, init, sel, job, frozen = ss.spawn(5)
     pick = lambda s: int(s.generate_state(1, np.uint32)[0] & 0x7FFFFFFF)
-    return {"data": data, "init": pick(init), "select": pick(sel), "job": pick(job)}
+    select = pick(sel)
+    if mix is not None and "selection_seed" in mix:
+        select = int(mix["selection_seed"])
+    return {"data": data, "init": pick(init), "select": select, "job": pick(job),
+            "frozen": pick(frozen)}
 
 
 def window_chunks(mix: Dict, seconds: float) -> int:
@@ -198,32 +227,29 @@ def run_cell(c: SimpleNamespace, seed: int, seconds: float, trace: bool, *,
     from repro.fl import FLrce, run_federated
     from repro.data.synthetic import FederatedDataset
     from repro.launch import make_engine_mesh
-    from repro.models.cnn import PaperCNN
 
     import compare
     import roofline
     import traffic as traffic_mod
-    from reference import Reference, leaf_sizes, make_init
+    from reference import Reference, leaf_sizes, make_weights
 
-    cfg, mix, name = c.cfg, c.mix, c.cell["name"]
-    seeds = derive_seeds(seed)
+    arch, cfg, mix, name = c.model, c.cfg, c.mix, c.cell["name"]
+    seeds = derive_seeds(seed, mix)
     notes: List[str] = []
 
     # --- set-up: data, weights, the strategy ------------------------------
     t0 = time.perf_counter()
-    counts = traffic_mod.fixed_counts(cfg, mix)
-    data = traffic_mod.make_traffic(cfg, mix, counts, seeds["data"])
+    counts = traffic_mod.fixed_counts(arch, cfg, mix)
+    data = traffic_mod.make_traffic(arch, cfg, mix, counts, seeds["data"])
     ds = FederatedDataset(x=data.x, y=data.y, client_indices=data.client_indices,
                           eval_x=data.eval_x, eval_y=data.eval_y,
-                          num_classes=int(cfg["num_classes"]))
+                          num_classes=arch.num_classes(cfg))
     t_data = time.perf_counter() - t0
-    init = make_init(cfg)
-    params0 = jax.block_until_ready(init(seeds["init"]))
-    sizes = leaf_sizes(params0)
+    weights = make_weights(arch, cfg)
+    params0, frozen = jax.block_until_ready(weights(seeds["init"], seeds["frozen"]))
+    sizes = leaf_sizes(params0)                  # the trained parameters only
     dim = sum(sizes)
-    model = PaperCNN(side=int(cfg["side"]), channels=int(cfg["channels"]),
-                     num_classes=int(cfg["num_classes"]), num_fc=int(cfg["num_fc"]),
-                     conv_channels=tuple(cfg["conv_channels"]), fc_width=int(cfg["fc_width"]))
+    model = arch.program_model(cfg, frozen)
 
     r_chunk = int(mix["chunk_rounds"])
     n_window = window_chunks(mix, seconds)
@@ -295,22 +321,29 @@ def run_cell(c: SimpleNamespace, seed: int, seconds: float, trace: bool, *,
     failed = sum(1 for r in win if not (math.isfinite(r.mean_client_loss)
                                         and math.isfinite(r.accuracy)))
     trained = sum(int(data.sizes[cid]) for r in win for cid in r.selected)
-    flops_window = roofline.cnn_flops_per_sample(cfg) * trained * int(mix["local_epochs"])
+    flops_window = arch.flops_per_sample(cfg) * trained * int(mix["local_epochs"])
+    longest = [max(traffic_mod.local_steps(int(data.sizes[cid]), int(mix["local_epochs"]),
+                                           int(mix["batch_size"])) for cid in r.selected)
+               for r in win]
     notes.append(
         f"set-up {setup_s:.3f} s: data {t_data:.3f} s, job start to chunk 0's flush "
         f"{window_start - t_job0:.3f} s, process start to job start "
         f"{t_job0 - t_process:.3f} s; window {window_s:.6f} s over {len(win)} rounds "
         f"({n_window} chunks of {r_chunk}); compiles in the window {in_window}; "
-        f"driver compiles_chunk {res.driver_stats.get('compiles_chunk')}"
+        f"driver compiles_chunk {res.driver_stats.get('compiles_chunk')}; model FLOPs "
+        f"in the window {flops_window!r} over {trained} samples trained; each window "
+        f"round's longest client, in local steps, {longest} ("
+        f"{''.join('x' if r.exploited else 'e' for r in win)}: explore or exploit)"
     )
 
     # --- read the program's state, free it, run the reference ---------------
     prog = observe(res, strategy, check_rounds, dim)
-    del res, strategy
+    del res, strategy, model, params0, frozen
     gc.collect()
-    params_ref = init(seeds["init"])
+    params_ref, frozen_ref = weights(seeds["init"], seeds["frozen"])
     t_ref = time.perf_counter()
-    ref = Reference(cfg, mix).run(data, params_ref, seeds["select"], seeds["job"], check_rounds)
+    ref = Reference(arch, cfg, mix).run(data, params_ref, frozen_ref, seeds["select"],
+                                        seeds["job"], check_rounds)
     notes.append(f"reference {time.perf_counter() - t_ref:.3f} s over {check_rounds} rounds "
                  + " ".join(f"{k} {v:.3f} s" for k, v in ref["seconds"].items()))
     psi = float(mix["es_threshold"])
@@ -321,6 +354,12 @@ def run_cell(c: SimpleNamespace, seed: int, seconds: float, trace: bool, *,
         notes.append(f"round {t}: exploited {a['exploited']} reference {b['exploited']}; "
                      f"loss {a['loss']!r} reference {b['loss']!r}; "
                      f"accuracy {a['accuracy']!r} reference {b['accuracy']!r}")
+    for g in compare.client_gaps(prog, ref, sizes, check_rounds):
+        to = g["widest_to"]
+        notes.append(f"client {g['client']} of {int(data.sizes[g['client']])} samples, round "
+                     f"{g['round']}: update gap {g['update']!r}, Omega row widest "
+                     f"{g['omega']!r} (to client {to} of "
+                     f"{int(data.sizes[to]) if to >= 0 else 0} samples)")
     last = prog["last"]
     notes.append(f"compared: {len(prog['updates'])} first-round updates, "
                  f"models after rounds {sorted(prog['models'])}; window's last round "
@@ -375,11 +414,7 @@ def load_metric(name: str):
     metrics = os.path.join(HERE, "metrics")
     if metrics not in sys.path:
         sys.path.insert(0, metrics)
-    path = os.path.join(metrics, f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _load_file(os.path.join(metrics, f"{name}.py"), f"bench_metric_{name}")
 
 
 def print_result(out: Dict) -> None:

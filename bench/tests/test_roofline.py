@@ -5,6 +5,7 @@ import os
 import pytest
 
 import roofline
+import run
 
 CONFIGS = os.path.join(roofline.HERE, "configs")
 
@@ -24,8 +25,9 @@ def config(name):
 ])
 def test_cnn_counts(name, flops, params):
     cfg = config(name)
-    assert roofline.cnn_flops_per_sample(cfg) == flops == cfg["flops_per_sample"]
-    assert roofline.cnn_param_count(cfg) == params == cfg["params"]
+    model = run.load_model(cfg["model"])
+    assert model.flops_per_sample(cfg) == flops == cfg["flops_per_sample"]
+    assert model.param_count(cfg) == params == cfg["params"]
 
 
 def test_cross_gram_cost():

@@ -1,21 +1,27 @@
 """Federated traffic for the benchmark: one general generator, driven by the
 parameters of a traffic mix (``bench/workloads/<traffic>.json``).
 
-Samples are synthetic class-conditional images: Gaussian class centres
-pushed through a frozen random two-layer teacher, reshaped to
-``(side, side, channels)``.  Clients hold Dirichlet label-skewed or
-quantity-skewed shards.  The generator and both partitions are copies of
-the program's own (``make_classification``, ``dirichlet_label_partition``,
-``dirichlet_quantity_partition``, ``make_image_like``), kept here so that a
-change to the program's data code cannot move the yardstick.
+Clients hold Dirichlet label-skewed or quantity-skewed shards.  Both
+partitions are copies of the program's own (``dirichlet_label_partition``,
+``dirichlet_quantity_partition``), kept here so that a change to the
+program's data code cannot move the yardstick.  The features of each label
+are the model's to draw: its module (``bench/models/<model>.py``) states
+``num_classes`` and gives a ``sampler``.
 
 The per-client sample counts (and, for label skew, each client's class
 counts) are a property of the mix: they are drawn once from the mix's
 ``partition_seed``, so every run of a cell trains on the same set of client
 sizes and its padded step bucket never changes.  ``--seed`` draws
 everything else: which client gets which shard, which label each class
-count stands for, the teacher, every feature, the eval set, the initial
+count stands for, the sampler, every feature, the eval set, the initial
 weights and FLrce's selection stream.
+
+A mix with a ``selection_seed`` fixes the cohorts too: client i holds the
+i-th counts, and FLrce's selection stream is seeded by ``selection_seed``
+(``run.derive_seeds``), so every explore round trains the same clients
+whatever ``--seed`` is, and a round, which runs to its cohort's longest
+client, does the same work; ``--seed`` still draws the labels, features,
+eval set, weights and batch order.
 """
 from __future__ import annotations
 
@@ -23,16 +29,6 @@ import dataclasses
 from typing import Dict, List
 
 import numpy as np
-
-TEACHER_HIDDEN = 64
-
-
-def _teacher_draw(rng: np.random.Generator, feature_dim: int, num_classes: int):
-    """The teacher, drawn and scaled as ``make_classification`` draws it."""
-    w1 = rng.normal(size=(feature_dim, TEACHER_HIDDEN)) / np.sqrt(feature_dim)
-    w2 = rng.normal(size=(TEACHER_HIDDEN, feature_dim)) / np.sqrt(TEACHER_HIDDEN)
-    centers = rng.normal(size=(num_classes, feature_dim)) * 1.8
-    return w1, w2, centers
 
 
 def label_partition(labels: np.ndarray, num_clients: int, alpha: float,
@@ -73,12 +69,13 @@ def quantity_sizes(num_samples: int, num_clients: int, alpha: float,
     return sizes.astype(np.int64)
 
 
-def fixed_counts(cfg: Dict, mix: Dict) -> np.ndarray:
+def fixed_counts(model, cfg: Dict, mix: Dict) -> np.ndarray:
     """The mix's per-client counts, drawn once from ``partition_seed``.
 
-    Label skew: an (M, classes) matrix of class counts, those the program's
-    ``make_image_like(seed=partition_seed)`` partition gives.  Quantity
-    skew: an (M, 1) column of sample counts.
+    Label skew: an (M, classes) matrix of class counts, drawn after the
+    model's sampler as the program's generator draws them (for the CNN,
+    those the program's ``make_image_like(seed=partition_seed)`` partition
+    gives).  Quantity skew: an (M, 1) column of sample counts.
     """
     m, seed = int(mix["clients"]), int(mix["partition_seed"])
     if mix["partition"] == "quantity":
@@ -86,23 +83,19 @@ def fixed_counts(cfg: Dict, mix: Dict) -> np.ndarray:
                               seed)[:, None]
     if mix["partition"] != "label":
         raise ValueError(f"unknown partition {mix['partition']!r}")
-    k = int(cfg["num_classes"])
+    k = model.num_classes(cfg)
     rng = np.random.default_rng(seed)
-    _teacher_draw(rng, feature_dim(cfg), k)
+    model.sampler(cfg, mix, rng)
     labels = rng.integers(0, k, size=int(mix["num_samples"])).astype(np.int32)
     parts = label_partition(labels, m, float(mix["alpha"]), seed)
     return np.stack([np.bincount(labels[p], minlength=k) for p in parts])
-
-
-def feature_dim(cfg: Dict) -> int:
-    return int(cfg["side"]) ** 2 * int(cfg["channels"])
 
 
 @dataclasses.dataclass
 class Traffic:
     """One run's data, as the harness hands it to the program."""
 
-    x: np.ndarray                 # (N, side, side, channels) float32
+    x: np.ndarray                 # (N, ...) float32, the model's features
     y: np.ndarray                 # (N,) int32
     client_indices: List[np.ndarray]
     eval_x: np.ndarray
@@ -110,16 +103,17 @@ class Traffic:
     sizes: np.ndarray             # (M,) int64, client_indices' lengths
 
 
-def make_traffic(cfg: Dict, mix: Dict, counts: np.ndarray,
+def make_traffic(model, cfg: Dict, mix: Dict, counts: np.ndarray,
                  seed_seq: np.random.SeedSequence) -> Traffic:
     """Draw one run's samples over the mix's fixed counts."""
     rng = np.random.default_rng(seed_seq)
-    m, k = counts.shape[0], int(cfg["num_classes"])
-    client_of_slot = rng.permutation(m)          # slot i's counts go to client
+    m, k = counts.shape[0], model.num_classes(cfg)
+    if "selection_seed" in mix:                  # slot i's counts go to client
+        client_of_slot = np.arange(m)
+    else:
+        client_of_slot = rng.permutation(m)
     class_of = rng.permutation(k)
-    w1, w2, centers = (a.astype(np.float32) for a in
-                       _teacher_draw(rng, feature_dim(cfg), k))
-    noise = float(mix["noise"])
+    draw = model.sampler(cfg, mix, rng)
     by_client: List[np.ndarray] = [None] * m     # type: ignore[list-item]
     for slot in range(m):
         row = counts[slot]
@@ -134,15 +128,6 @@ def make_traffic(cfg: Dict, mix: Dict, counts: np.ndarray,
     client_indices = [np.arange(s, s + n, dtype=np.int64)
                       for s, n in zip(starts, sizes)]
     eval_y = rng.integers(0, k, size=int(mix["num_eval"])).astype(np.int32)
-    shape = (-1, int(cfg["side"]), int(cfg["side"]), int(cfg["channels"]))
-
-    def draw(labels):
-        z = centers[labels]
-        z += noise * rng.standard_normal(z.shape, dtype=np.float32)
-        x = np.tanh(z @ w1) @ w2
-        x += 0.1 * z
-        return x.reshape(shape)
-
     return Traffic(x=draw(y), y=y, client_indices=client_indices,
                    eval_x=draw(eval_y), eval_y=eval_y, sizes=sizes)
 
