@@ -10,7 +10,7 @@ import dataclasses
 import importlib
 from typing import Dict, List
 
-from repro.configs.base import ArchConfig, FLConfig, MoEConfig, ShapeConfig
+from repro.configs.base import ArchConfig, FLConfig, MLAConfig, MoEConfig, ShapeConfig
 from repro.configs.shapes import SHAPES, get_shape
 
 _ARCH_MODULES = {
@@ -24,6 +24,7 @@ _ARCH_MODULES = {
     "whisper-medium": "repro.configs.whisper_medium",
     "minitron-4b": "repro.configs.minitron_4b",
     "deepseek-7b": "repro.configs.deepseek_7b",
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
 }
 
 
@@ -54,11 +55,16 @@ def reduce_config(cfg: ArchConfig) -> ArchConfig:
     num_layers = min(2, cfg.num_layers)
     moe = None
     if cfg.moe is not None:
-        moe = MoEConfig(
+        moe = dataclasses.replace(
+            cfg.moe,
             num_experts=min(4, cfg.moe.num_experts),
             top_k=min(2, cfg.moe.top_k),
-            aux_loss_weight=cfg.moe.aux_loss_weight,
+            expert_ff=max(64, cfg.moe.expert_ff // shrink) if cfg.moe.expert_ff else 0,
         )
+    mla = None
+    if cfg.mla is not None:
+        mla = MLAConfig(*(max(8, getattr(cfg.mla, f.name) // shrink)
+                          for f in dataclasses.fields(MLAConfig)))
     return dataclasses.replace(
         cfg,
         name=cfg.name + "-reduced",
@@ -71,6 +77,9 @@ def reduce_config(cfg: ArchConfig) -> ArchConfig:
         vocab_size=min(1024, cfg.vocab_size),
         window=min(64, cfg.window) if cfg.window else 0,
         moe=moe,
+        mla=mla,
+        # keep a leading dense layer and at least one layer after it
+        first_dense_layers=min(cfg.first_dense_layers, num_layers - 1),
         encoder_layers=min(2, cfg.encoder_layers),
         encoder_frames=min(16, cfg.encoder_frames),
         image_tokens=min(8, cfg.image_tokens),
@@ -85,6 +94,7 @@ def all_configs(*, reduced: bool = False) -> Dict[str, ArchConfig]:
 __all__ = [
     "ArchConfig",
     "FLConfig",
+    "MLAConfig",
     "MoEConfig",
     "ShapeConfig",
     "SHAPES",

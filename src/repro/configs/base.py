@@ -18,6 +18,7 @@ ATTN_CROSS = "attn_cross"        # encoder-decoder cross attention (whisper)
 MLSTM = "mlstm"                  # xLSTM matrix-memory block (parallel form)
 SLSTM = "slstm"                  # xLSTM scalar-memory block (recurrent scan)
 RGLRU = "rglru"                  # RG-LRU recurrent block (Griffin/recurrentgemma)
+ATTN_MLA = "attn_mla"            # multi-head latent attention (DeepSeek-V2)
 
 FAMILIES = ("dense", "moe", "ssm", "hybrid", "vlm", "audio")
 
@@ -30,6 +31,51 @@ class MoEConfig:
     top_k: int
     # load-balance auxiliary loss weight (Switch-style)
     aux_loss_weight: float = 0.01
+    # width of one routed expert's FFN; 0 means the config's ``d_ff``
+    expert_ff: int = 0
+    # shared experts, run as one MLP of ``num_shared_experts * expert width``
+    # on every token and added to the routed experts' output (DeepSeek)
+    num_shared_experts: int = 0
+    # renormalise the chosen top-k gates to sum to 1 (Mixtral) or not (DeepSeek)
+    norm_topk_prob: bool = True
+    # factor on the routed experts' gates
+    routed_scaling_factor: float = 1.0
+    # route every token to its top-k experts with no capacity (grouped expert
+    # products, no token dropped) on every pass, not only on decode
+    dropless: bool = False
+
+    def expert_width(self, d_ff: int) -> int:
+        return self.expert_ff or d_ff
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2): keys and values come from a
+    compressed latent ``c_kv`` of ``kv_lora_rank``; each head's query and key
+    are a ``qk_nope_head_dim`` part without position and a
+    ``qk_rope_head_dim`` part under RoPE (one rope key shared by all heads).
+    Queries are not compressed (``q_lora_rank`` null)."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (``rope_scaling`` of type ``yarn``)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,6 +116,15 @@ class ArchConfig:
     citation: str = ""
     # dtype of params/activations for the production dry-run
     dtype: str = "bfloat16"
+    # dtype of the residual stream where it differs from ``dtype`` (weights
+    # in ``dtype``, activations in this); "" means ``dtype``
+    residual_dtype: str = ""
+    # latent attention, for ATTN_MLA blocks
+    mla: Optional[MLAConfig] = None
+    rope_scaling: Optional[YarnScaling] = None
+    # leading dense layers (DeepSeek's first_k_dense_replace): attention +
+    # a dense MLP of width ``d_ff`` before the MoE layers
+    first_dense_layers: int = 0
 
     @property
     def resolved_head_dim(self) -> int:
@@ -93,8 +148,9 @@ class ArchConfig:
         total = v * d                         # embedding
         if not self.tie_embeddings:
             total += v * d                    # unembedding
-        for kind in self.layer_kinds():
-            total += self._block_params(kind, d, f, h, kv, hd)
+        for i, kind in enumerate(self.layer_kinds()):
+            total += self._block_params(kind, d, f, h, kv, hd,
+                                        dense=i < self.first_dense_layers)
         total += d                            # final norm
         if self.is_encdec:
             for _ in range(self.encoder_layers):
@@ -102,9 +158,19 @@ class ArchConfig:
             total += d
         return total
 
-    def _block_params(self, kind: str, d: int, f: int, h: int, kv: int, hd: int) -> int:
+    def _mla_params(self, d: int, h: int) -> int:
+        m = self.mla
+        return (d * h * m.qk_head_dim + d * (m.kv_lora_rank + m.qk_rope_head_dim)
+                + m.kv_lora_rank                                  # kv_a_layernorm
+                + m.kv_lora_rank * h * (m.qk_nope_head_dim + m.v_head_dim)
+                + h * m.v_head_dim * d)
+
+    def _block_params(self, kind: str, d: int, f: int, h: int, kv: int, hd: int,
+                      dense: bool = False) -> int:
         n = 2 * d  # two norms per block (pre-attn/pre-mlp or equivalents)
-        if kind in (ATTN_GLOBAL, ATTN_LOCAL, ATTN_CROSS):
+        if kind == ATTN_MLA:
+            n += self._mla_params(d, h)
+        elif kind in (ATTN_GLOBAL, ATTN_LOCAL, ATTN_CROSS):
             n += d * (h * hd) + 2 * d * (kv * hd) + (h * hd) * d
             if self.qkv_bias:
                 n += h * hd + 2 * kv * hd
@@ -119,21 +185,30 @@ class ArchConfig:
             inner = 3 * d // 2  # griffin uses 1.5x expansion
             n += 2 * d * inner + inner * d + 2 * inner + 4 * inner
         if kind != MLSTM and kind != SLSTM and f > 0:
-            per_expert = (3 if self.gated_mlp else 2) * d * f
-            if self.moe is not None:
+            mats = 3 if self.gated_mlp else 2
+            if self.moe is not None and not dense:
+                per_expert = mats * d * self.moe.expert_width(f)
                 n += self.moe.num_experts * per_expert + d * self.moe.num_experts
+                n += self.moe.num_shared_experts * per_expert
             else:
-                n += per_expert
+                n += mats * d * f
         return n
+
+    @property
+    def num_moe_layers(self) -> int:
+        if self.moe is None:
+            return 0
+        return sum(1 for i, kind in enumerate(self.layer_kinds())
+                   if i >= self.first_dense_layers and kind not in (MLSTM, SLSTM))
 
     def active_param_count(self) -> int:
         """Params touched per token (MoE counts only top-k experts)."""
         if self.moe is None:
             return self.param_count()
-        d, f = self.d_model, self.d_ff
-        per_expert = (3 if self.gated_mlp else 2) * d * f
+        d = self.d_model
+        per_expert = (3 if self.gated_mlp else 2) * d * self.moe.expert_width(self.d_ff)
         inactive = (self.moe.num_experts - self.moe.top_k) * per_expert
-        return self.param_count() - self.num_layers * inactive
+        return self.param_count() - self.num_moe_layers * inactive
 
 
 @dataclasses.dataclass(frozen=True)

@@ -22,6 +22,7 @@ epochs, matching the paper's u_k (the aggregate of E epochs of SGD).
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
@@ -348,12 +349,20 @@ class BatchedCohortTrainer:
         chunks use it to restore the store's flat samples to the model's
         feature shape and, where the store is not split over devices, to
         gather each step's batch from it (scan_driver).
+
+        A model that counts its experts' routed assignments
+        (``expert_counts_shape``) adds a fourth output: each client's per-layer,
+        per-expert count over its real samples and steps, (P, L, E).
         """
         model, lr = self.model, self.lr
+        counts_shape = getattr(model, "expert_counts_shape", None)
+        counting = counts_shape is not None
 
         def per_example_losses(p, x, y):
             # model.loss over a single-sample batch == that sample's loss;
             # vmap re-batches it, matching the sequential batched compute.
+            if counting:
+                return jax.vmap(lambda xi, yi: model.loss_and_counts(p, xi[None], yi[None]))(x, y)
             return jax.vmap(lambda xi, yi: model.loss(p, xi[None], yi[None]))(x, y)
 
         def step(params, global_params, x, y, w, v, mask, freeze, prox_mu, *, use_prox, has_mask):
@@ -363,6 +372,9 @@ class BatchedCohortTrainer:
             def loss_fn(p):
                 q = jax.tree_util.tree_map(lambda a, m: a * m, p, mask) if has_mask else p
                 per = per_example_losses(q, x, y)
+                if counting:
+                    per, counts = per
+                    counts = jnp.sum(counts * (w > 0)[:, None, None].astype(counts.dtype), 0)
                 base = jnp.sum(per * w) / jnp.maximum(jnp.sum(w), 1.0)
                 if use_prox:
                     # on the MASKED params, matching ClientTrainer's loss_fn
@@ -374,14 +386,19 @@ class BatchedCohortTrainer:
                         )
                     )
                     base = base + 0.5 * prox_mu * sq
-                return base
+                return (base, counts) if counting else base
 
-            loss, grads = jax.value_and_grad(loss_fn)(params)
+            if counting:
+                (loss, counts), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+            else:
+                loss, grads = jax.value_and_grad(loss_fn)(params)
             if has_mask:
                 grads = jax.tree_util.tree_map(lambda g, m: g * m, grads, mask)
             # freeze flags and the step-validity flag both gate the update
             grads = jax.tree_util.tree_map(lambda g, f: g * (f * v), grads, freeze)
             new_params = jax.tree_util.tree_map(lambda a, g: a - lr * g, params, grads)
+            if counting:
+                return new_params, loss, counts * v
             return new_params, loss
 
         def train(global_params, xs, ys, ws, valid, mask, freeze, prox_mu, *, use_prox, has_mask):
@@ -392,17 +409,20 @@ class BatchedCohortTrainer:
             )
 
             def body(i, carry):
-                params, losses = carry
+                params, losses = carry[:2]
                 x, y, w, v = (
                     jax.lax.dynamic_index_in_dim(a, i, axis=1, keepdims=False)
                     for a in (xs, ys, ws, valid)
                 )
-                params, loss = cohort_step(
+                out = cohort_step(
                     params, global_params, x, y, w, v, mask, freeze, prox_mu
                 )
+                params, loss = out[:2]
                 losses = jax.lax.dynamic_update_index_in_dim(
                     losses, loss.astype(losses.dtype), i, axis=1
                 )
+                if counting:
+                    return params, losses, carry[2] + out[2]
                 return params, losses
 
             start = (
@@ -413,8 +433,10 @@ class BatchedCohortTrainer:
                 # donated validity buffer still aliases it
                 jnp.zeros((p, s), valid.dtype),
             )
-            final, losses = jax.lax.fori_loop(0, steps_to_run(valid), body, start)
-            updates = tree_sub(final, global_params)
+            if counting:
+                start = start + (jnp.zeros((p, *counts_shape), jnp.float32),)
+            final = jax.lax.fori_loop(0, steps_to_run(valid), body, start)
+            updates = tree_sub(final[0], global_params)
             if has_mask:
                 updates = jax.tree_util.tree_map(lambda u, m: u * m, updates, mask)
             flat = jnp.concatenate(
@@ -422,9 +444,19 @@ class BatchedCohortTrainer:
                  for l in jax.tree_util.tree_leaves(updates)],
                 axis=1,
             )
-            return updates, flat, losses
+            return (updates, flat, *final[1:])
 
         return train
+
+    def rebind(self, model) -> "BatchedCohortTrainer":
+        """This trainer over ``model`` (a model bound to a chunk's traced
+        frozen weights); programs built from it are not cached."""
+        other = copy.copy(self)
+        other.model = model
+        for cache in ("_sharded_raw_cache", "_sharded_train_cache"):
+            if hasattr(self, cache):
+                setattr(other, cache, {})
+        return other
 
     def train_cohort(
         self,
@@ -441,7 +473,7 @@ class BatchedCohortTrainer:
         freeze = stack_freeze_flags(global_params, freeze_fracs)
         mu = jnp.asarray(np.asarray(prox_mus, np.float32))
         use_prox = bool(np.any(np.asarray(prox_mus) > 0.0))
-        updates, flat, losses = self._train(
+        updates, flat, losses, *_ = self._train(
             global_params,
             jnp.asarray(plan.x),
             jnp.asarray(plan.y),
@@ -524,6 +556,7 @@ class ShardedCohortTrainer(BatchedCohortTrainer):
         key = (use_prox, has_mask)
         if load is None and key in self._sharded_raw_cache:
             return self._sharded_raw_cache[key]
+        counting = getattr(self.model, "expert_counts_shape", None) is not None
         train = functools.partial(
             self._make_train(load), use_prox=use_prox, has_mask=has_mask
         )
@@ -535,7 +568,7 @@ class ShardedCohortTrainer(BatchedCohortTrainer):
 
             dspec = P(self.data_axis)
             in_specs = (P(), dspec, dspec, dspec, dspec, dspec, dspec, dspec)
-            out_specs = (dspec, P(self.data_axis, None), dspec)
+            out_specs = (dspec, P(self.data_axis, None), dspec) + ((dspec,) if counting else ())
             program = _shard_map(train, self.mesh, in_specs, out_specs)
         if load is None:
             self._sharded_raw_cache[key] = program
@@ -568,12 +601,10 @@ class ShardedCohortTrainer(BatchedCohortTrainer):
             xs, ys, ws, valid = (pin_rows(t) for t in (xs, ys, ws, valid))
             mask = jax.tree_util.tree_map(pin_rows, mask)
             prox_mu = pin_rows(prox_mu)
-            updates, flat, losses = train(
+            updates, flat, *rest = train(
                 gp, xs, ys, ws, valid, mask, freeze, prox_mu
             )
-            flat = pin_rows(flat)
-            losses = pin_rows(losses)
-            return updates, flat, losses
+            return (updates, pin_rows(flat), *(pin_rows(r) for r in rest))
 
         return run
 
@@ -669,7 +700,7 @@ class ShardedCohortTrainer(BatchedCohortTrainer):
         mu = jnp.asarray(np.asarray(list(prox_mus) + [0.0] * n_pad, np.float32))
         use_prox = bool(np.any(np.asarray(prox_mus) > 0.0))
         train = self._sharded_train(use_prox, has_mask)
-        updates, flat, losses = train(
+        updates, flat, losses, *_ = train(
             global_params,
             jnp.asarray(padded.x),
             jnp.asarray(padded.y),

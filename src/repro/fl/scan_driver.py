@@ -237,6 +237,13 @@ class _ChunkRunner:
         self._resident = None if paged else (
             store.x, store.y, put(store.sizes.astype(jnp.float32))
         )
+        # a model's frozen weights (LoRA's base) enter every chunk as an
+        # argument that is never donated: held once on the device, and never
+        # baked into the program as constants.  None (no frozen weights)
+        # adds no argument.
+        self.frozen = getattr(model, "frozen", None)
+        if self.frozen is not None:
+            self.frozen = jax.tree_util.tree_map(put, self.frozen)
 
     def _build(self, use_prox: bool, has_mask: bool, carry_shardings=None):
         program, unflatten = self.program, self.unflatten
@@ -269,7 +276,7 @@ class _ChunkRunner:
                         unflatten(wv), param_shardings,
                     )
 
-        def body_with(cand, page_x, page_y, page_sizes, eval_x, eval_y):
+        def body_with(cand, page_x, page_y, page_sizes, eval_x, eval_y, frozen):
             """The scan body, closed over this chunk's candidate remap.
 
             ``cand`` is the chunk's (P_cand,) sorted global candidate ids;
@@ -286,6 +293,8 @@ class _ChunkRunner:
             def unflat(x):
                 return x.reshape(*x.shape[:-1], *feat_shape)
 
+            bound = model if frozen is None else model.bind(frozen)
+
             if step_gather:
                 def load(pos, rows):
                     return unflat(page_x[rows, pos]), page_y[rows, pos]
@@ -293,10 +302,13 @@ class _ChunkRunner:
                 def load(x, y):
                     return unflat(x), y
 
+            # a model with frozen weights trains through a trainer over the
+            # model bound to this trace's frozen argument
+            cohort = trainer if frozen is None else trainer.rebind(bound)
             if mesh is None:
-                train = trainer._make_train(load)
+                train = cohort._make_train(load)
             else:
-                train_sharded = trainer._sharded_train_raw(use_prox, has_mask, load)
+                train_sharded = cohort._sharded_train_raw(use_prox, has_mask, load)
 
             def body(carry, x_t):
                 w, sc, abuf, stopped, last_acc = carry
@@ -322,7 +334,7 @@ class _ChunkRunner:
                         x, y = bi, jnp.broadcast_to(rows, bi.shape)
                         sw, sv = sw_t[slots], sv_t[slots]
                         mu = prox_t[slots]
-                        _, flat, losses = train(
+                        _, flat, losses, *counts = train(
                             params_t, x, y, sw, sv, mask_t, freeze_t, mu,
                             use_prox=use_prox, has_mask=has_mask,
                         )
@@ -361,7 +373,7 @@ class _ChunkRunner:
                             valid_row = (jnp.arange(p_pad) < p).astype(sv.dtype)
                             sv = sv * valid_row[:, None]
                         mu = prox_t[slots_pad]
-                        _, flat, losses = train_sharded(
+                        _, flat, losses, *counts = train_sharded(
                             params_t, x, y, sw, sv, mask_t, freeze_t, mu
                         )
                 if mesh is not None:
@@ -491,6 +503,16 @@ class _ChunkRunner:
                     )
                     useful_steps = jnp.sum(cnt).astype(jnp.int32)
                     steps_run = steps_to_run(sv)
+                    if counts:
+                        # each MoE layer's largest per-expert count of the
+                        # round's routed assignments over the mean count,
+                        # averaged over the layers that route
+                        per_layer = jnp.sum(counts[0], axis=0)          # (L, E)
+                        mean_e = jnp.mean(per_layer, axis=1)
+                        routes = mean_e > 0.0
+                        ratio = jnp.max(per_layer, axis=1) / jnp.maximum(mean_e, 1e-30)
+                        expert_load = jnp.sum(jnp.where(routes, ratio, 0.0)) / jnp.maximum(
+                            jnp.sum(routes.astype(jnp.float32)), 1.0)
 
                 # --- evaluation (only when the loop driver would) ---------------
                 with jax.named_scope("fl/eval"):
@@ -499,7 +521,7 @@ class _ChunkRunner:
                     )
                     acc = jax.lax.cond(
                         evaluated,
-                        lambda wv: model.accuracy(
+                        lambda wv: bound.accuracy(
                             eval_params(wv), eval_x, eval_y
                         ).astype(jnp.float32),
                         lambda wv: last_acc,
@@ -526,6 +548,8 @@ class _ChunkRunner:
                     "steps_run": steps_run,
                     "valid": jnp.logical_not(stopped),
                 }
+                if counts:
+                    out["expert_load"] = expert_load
                 if tau_hist is not None:
                     out["tau_hist"] = tau_hist
                 return carry_out, out
@@ -547,8 +571,8 @@ class _ChunkRunner:
             return w, sc, abuf, stopped, last_acc, outs
 
         def chunk(w, sc, abuf, stopped, last_acc, cand, page_x, page_y,
-                  page_sizes, eval_x, eval_y, xs):
-            body = body_with(cand, page_x, page_y, page_sizes, eval_x, eval_y)
+                  page_sizes, eval_x, eval_y, xs, frozen):
+            body = body_with(cand, page_x, page_y, page_sizes, eval_x, eval_y, frozen)
             carry = jax.lax.scan(body, (w, sc, abuf, stopped, last_acc), xs)
             return finish(*carry)
 
@@ -558,8 +582,8 @@ class _ChunkRunner:
         # the accuracy scalar alias their outputs — no per-chunk copy of the
         # O(M·D) state.  The candidate remap, the page (or resident store)
         # and the eval set are NOT donated: at pipeline depth 2 the two
-        # in-flight chunks each hold their own page, and the store and eval
-        # set serve every chunk.
+        # in-flight chunks each hold their own page, and the store, the eval
+        # set and the frozen weights serve every chunk.
         return jax.jit(chunk, donate_argnums=(0, 1, 2, 3, 4))
 
     def run_chunk(self, w, sc, abuf, stopped, last_acc, cand, page, xs,
@@ -567,7 +591,7 @@ class _ChunkRunner:
         key = (use_prox, has_mask)
         page_x, page_y, page_sizes = page if self.paged else self._resident
         args = (w, sc, abuf, stopped, last_acc, cand, page_x, page_y,
-                page_sizes, self.eval_x, self.eval_y, xs)
+                page_sizes, self.eval_x, self.eval_y, xs, self.frozen)
         if key not in self._cache:
             shardings = None
             if self.mesh is not None:
@@ -1038,6 +1062,7 @@ def run_scan_driver(
                           sched_bytes=int(sched.nbytes), page_bytes=page_bytes)
 
     records: List[RoundRecord] = []
+    load_rounds: List[float] = []            # expert_load of each flushed round
 
     def flush_chunk(plan: _ChunkPlan, outs) -> Tuple[int, bool]:
         """Consume one chunk's host-fetched outputs: ledger + RoundRecords +
@@ -1084,6 +1109,9 @@ def run_scan_driver(
             )
             records.append(rec)
             stats["steps_useful"] += int(outs["useful_steps"][i])
+            if "expert_load" in outs:
+                load_rounds.append(float(outs["expert_load"][i]))
+                stats["expert_load_max_over_mean"] = float(np.mean(load_rounds))
             stats["steps_executed"] += runner.p_pad * int(outs["steps_run"][i])
             stats["steps_bucket"] += runner.p_pad * plan.steps
             flushed += 1

@@ -53,6 +53,12 @@ DRIVER_STATS_SCHEMA: Dict[str, Dict[str, Tuple[type, ...]]] = {
         "compiles_chunk": _INT,
         "compiles_total": _INT,
     },
+    # models that count their experts' routed assignments (a dropless MoE)
+    # report the mean over flushed rounds of each round's largest per-expert
+    # count over the mean count (averaged over its MoE layers)
+    "moe": {
+        "expert_load_max_over_mean": _NUM,
+    },
     # async_rounds=AsyncConfig(...) jobs additionally report the staleness leg
     "async": {
         "async_max_staleness": _INT,
@@ -100,7 +106,11 @@ def validate_driver_stats(stats: Mapping[str, Any]) -> None:
                     f"driver_stats has async keys but is missing {key!r}"
                 )
             _check_type(key, stats[key], types)
-    known = set(base) | (set(asyn) if has_async else set()) | OPTIONAL_EXTRAS
+    moe = DRIVER_STATS_SCHEMA["moe"]
+    for key, types in moe.items():
+        if key in stats:
+            _check_type(key, stats[key], types)
+    known = set(base) | set(moe) | (set(asyn) if has_async else set()) | OPTIONAL_EXTRAS
     unknown = set(stats) - known
     if unknown:
         raise ValueError(

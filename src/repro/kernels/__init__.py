@@ -4,6 +4,7 @@ gram.py             pairwise Gram matrix (relationship modeling, Eq. 5 / Alg. 3)
 aggregate.py        fused weighted aggregation (Eq. 4)
 topk_mask.py        block-local magnitude sparsification (Fedcom baseline)
 decode_attention.py flash-decoding GQA attention (serving shapes)
+moe_gmm.py          grouped expert products (dropless MoE)
 ops.py              public entry points
 ref.py              pure-jnp oracles
 

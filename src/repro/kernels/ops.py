@@ -10,12 +10,14 @@ from __future__ import annotations
 from repro.kernels.aggregate import weighted_aggregate
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.gram import cross_gram, gram
+from repro.kernels.moe_gmm import gmm as moe_gmm
 from repro.kernels.topk_mask import topk_mask, topk_mask_rows
 
 __all__ = [
     "cross_gram",
     "decode_attention",
     "gram",
+    "moe_gmm",
     "topk_mask",
     "topk_mask_rows",
     "weighted_aggregate",

@@ -58,3 +58,13 @@ def decode_attention_ref(
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgs,bskd->bkgd", probs, vf)
     return out.reshape(b, h, hd)
+
+
+def moe_gmm_ref(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
+                transpose_rhs: bool = False) -> jax.Array:
+    """Grouped product oracle: ``lax.ragged_dot`` over rows sorted by group,
+    operands in their own dtype, accumulated in float32."""
+    if transpose_rhs:
+        rhs = jnp.swapaxes(rhs, 1, 2)
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
+                              preferred_element_type=jnp.float32)

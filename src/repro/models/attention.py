@@ -16,7 +16,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.models.layers import apply_norm, apply_rope, dense_init, init_norm
+from repro.models.layers import (
+    apply_norm,
+    apply_rope,
+    apply_rope_yarn,
+    dense,
+    dense_init,
+    init_norm,
+    yarn_mscale,
+)
 
 _NEG_INF = -1e30
 DEFAULT_KV_CHUNK = 1024
@@ -85,12 +93,19 @@ def chunked_attention(
     causal: bool,
     window: int = 0,
     kv_chunk: int = DEFAULT_KV_CHUNK,
+    scale: Optional[float] = None,
 ) -> jax.Array:
-    """Online-softmax attention, scanning KV in chunks.  Returns (B,S,H,hd)."""
+    """Online-softmax attention, scanning KV in chunks.  Returns (B,S,H,hd_v)
+    (``hd_v`` is v's head size, which may differ from q's and k's);
+    ``scale`` defaults to ``1/sqrt(hd)``.  The backward pass recomputes each
+    chunk's scores instead of keeping every chunk's (B, S, H, C)
+    probabilities (0.5 GB a chunk at 8 x 2,048 positions, 16 heads and
+    C = 512)."""
     b, s, h, hd = q.shape
     skv, kvh = k.shape[1], k.shape[2]
+    hd_v = v.shape[-1]
     group = h // kvh
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     qg = q.reshape(b, s, kvh, group, hd)
 
     # pad KV to a chunk multiple; padded positions get -1 (always masked)
@@ -101,7 +116,7 @@ def chunked_attention(
         kv_positions = jnp.pad(kv_positions, ((0, 0), (0, pad)), constant_values=-1)
     n_chunks = (skv + pad) // kv_chunk
     k_chunks = k.reshape(b, n_chunks, kv_chunk, kvh, hd).transpose(1, 0, 2, 3, 4)
-    v_chunks = v.reshape(b, n_chunks, kv_chunk, kvh, hd).transpose(1, 0, 2, 3, 4)
+    v_chunks = v.reshape(b, n_chunks, kv_chunk, kvh, hd_v).transpose(1, 0, 2, 3, 4)
     p_chunks = kv_positions.reshape(b, n_chunks, kv_chunk).transpose(1, 0, 2)
 
     def body(carry, xs):
@@ -122,12 +137,13 @@ def chunked_attention(
         l_run = l_run * alpha + l_c * beta
         return (acc, m_new, l_run), None
 
-    acc0 = jnp.zeros((b, s, kvh, group, hd), jnp.float32)
+    acc0 = jnp.zeros((b, s, kvh, group, hd_v), jnp.float32)
     m0 = jnp.full((b, s, kvh, group), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, s, kvh, group), jnp.float32)
-    (acc, _, l), _ = jax.lax.scan(body, (acc0, m0, l0), (k_chunks, v_chunks, p_chunks))
+    (acc, _, l), _ = jax.lax.scan(jax.checkpoint(body), (acc0, m0, l0),
+                                  (k_chunks, v_chunks, p_chunks))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.reshape(b, s, h, hd).astype(q.dtype)
+    return out.reshape(b, s, h, hd_v).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -243,3 +259,111 @@ def attention_decode_step(
     )
     new_cache = {"k": k_cache, "v": v_cache}
     return out.reshape(b, 1, -1) @ params["wo"], new_cache
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2)
+# ---------------------------------------------------------------------------
+MLA_KV_CHUNK = 512
+
+
+def init_mla(rng, cfg: ArchConfig, dtype) -> Dict:
+    """MLA's projections, named as DeepSeek-V2's modeling code names them:
+    ``q_proj`` (D, H·(nope+rope)), ``kv_a_proj`` (D, kv_lora + rope; the
+    ``kv_a_proj_with_mqa``), ``kv_norm`` (the ``kv_a_layernorm``),
+    ``kv_b_proj`` (kv_lora, H·(nope+v)) and ``o_proj`` (H·v, D)."""
+    m, d, h = cfg.mla, cfg.d_model, cfg.num_heads
+    rq, ra, rb, ro = jax.random.split(rng, 4)
+    return {
+        "q_proj": dense_init(rq, d, h * m.qk_head_dim, dtype),
+        "kv_a_proj": dense_init(ra, d, m.kv_lora_rank + m.qk_rope_head_dim, dtype),
+        "kv_norm": init_norm("rmsnorm", m.kv_lora_rank, dtype),
+        "kv_b_proj": dense_init(rb, m.kv_lora_rank, h * (m.qk_nope_head_dim + m.v_head_dim), dtype),
+        "o_proj": dense_init(ro, h * m.v_head_dim, d, dtype),
+    }
+
+
+def mla_softmax_scale(cfg: ArchConfig) -> float:
+    """``qk_head_dim^-0.5``, times YaRN's ``mscale(factor, mscale_all_dim)²``
+    where the rope is scaled (0.11472 for DeepSeek-V2-Lite)."""
+    scale = cfg.mla.qk_head_dim ** -0.5
+    ys = cfg.rope_scaling
+    if ys is not None and ys.mscale_all_dim:
+        scale *= yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(x: jax.Array, positions: jax.Array, cfg: ArchConfig) -> jax.Array:
+    if cfg.rope_scaling is None:
+        # same de-interleaved layout as the YaRN path, at factor 1
+        from repro.configs.base import YarnScaling
+
+        return apply_rope_yarn(x, positions, cfg.rope_theta, YarnScaling(1.0, 1))
+    return apply_rope_yarn(x, positions, cfg.rope_theta, cfg.rope_scaling)
+
+
+def _mla_qkv(params, x: jax.Array, positions: jax.Array, cfg: ArchConfig):
+    """Queries (B,S,H,nope+rope), the normalized latent (B,S,kv_lora) and the
+    roped shared key part (B,S,rope)."""
+    m, h = cfg.mla, cfg.num_heads
+    b, s = x.shape[:2]
+    q = dense(x, params["q_proj"]).reshape(b, s, h, m.qk_head_dim)
+    q_nope, q_pe = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
+    ckv = dense(x, params["kv_a_proj"])
+    c, k_pe = ckv[..., : m.kv_lora_rank], ckv[..., m.kv_lora_rank:]
+    c = apply_norm("rmsnorm", params["kv_norm"], c)
+    q_pe = _mla_rope(q_pe, positions, cfg)
+    k_pe = _mla_rope(k_pe[:, :, None, :], positions, cfg)[:, :, 0, :]
+    return jnp.concatenate([q_nope, q_pe], axis=-1), c, k_pe
+
+
+def _mla_kv(params, c: jax.Array, k_pe: jax.Array, cfg: ArchConfig):
+    """Keys (B,L,H,nope+rope) and values (B,L,H,v) from the latent."""
+    m, h = cfg.mla, cfg.num_heads
+    b, l = c.shape[:2]
+    kv = dense(c, params["kv_b_proj"]).reshape(b, l, h, m.qk_nope_head_dim + m.v_head_dim)
+    k_nope, v = kv[..., : m.qk_nope_head_dim], kv[..., m.qk_nope_head_dim:]
+    k_pe = jnp.broadcast_to(k_pe[:, :, None, :], (b, l, h, m.qk_rope_head_dim))
+    return jnp.concatenate([k_nope, k_pe.astype(k_nope.dtype)], axis=-1), v
+
+
+def mla_block(params, x: jax.Array, positions: jax.Array, cfg: ArchConfig,
+              kv_chunk: int = MLA_KV_CHUNK) -> jax.Array:
+    """Causal MLA over the whole sequence (training / prefill), without the
+    norms and residual: ``q = x·W_q``; ``[c_kv, k_pe] = x·W_kva``;
+    ``[k_nope, v] = RMSNorm(c_kv)·W_kvb``; YaRN RoPE on ``q_pe`` and the
+    shared ``k_pe``; chunked scores at ``mla_softmax_scale``; ``o·W_o``."""
+    b, s = x.shape[:2]
+    with jax.named_scope("mla/proj"):
+        q, c, k_pe = _mla_qkv(params, x, positions, cfg)
+        k, v = _mla_kv(params, c, k_pe, cfg)
+    with jax.named_scope("mla/attend"):
+        out = chunked_attention(q, k, v, positions, positions, causal=True,
+                                kv_chunk=min(kv_chunk, s), scale=mla_softmax_scale(cfg))
+    with jax.named_scope("mla/proj"):
+        return dense(out.reshape(b, s, -1), params["o_proj"])
+
+
+def init_mla_cache(cfg: ArchConfig, batch: int, cache_len: int, dtype) -> Dict:
+    """The latent cache: the normalized ``c_kv`` and the roped ``k_pe``."""
+    m = cfg.mla
+    return {"ckv": jnp.zeros((batch, cache_len, m.kv_lora_rank), dtype),
+            "kpe": jnp.zeros((batch, cache_len, m.qk_rope_head_dim), dtype)}
+
+
+def mla_decode_step(params, x_t: jax.Array, cache: Dict, position: jax.Array,
+                    cfg: ArchConfig) -> Tuple[jax.Array, Dict]:
+    """One decode step over the latent cache; keys and values are expanded
+    from the latents of every cached position."""
+    b = x_t.shape[0]
+    pos = jnp.broadcast_to(jnp.reshape(position, (1, 1)).astype(jnp.int32), (b, 1))
+    q, c, k_pe = _mla_qkv(params, x_t, pos, cfg)
+    ckv = jax.lax.dynamic_update_slice_in_dim(cache["ckv"], c.astype(cache["ckv"].dtype), position, axis=1)
+    kpe = jax.lax.dynamic_update_slice_in_dim(cache["kpe"], k_pe.astype(cache["kpe"].dtype), position, axis=1)
+    k, v = _mla_kv(params, ckv, kpe, cfg)
+    logits = jnp.einsum("bhd,blhd->bhl", q[:, 0].astype(jnp.float32),
+                        k.astype(jnp.float32)) * mla_softmax_scale(cfg)
+    valid = jnp.arange(ckv.shape[1])[None, None, :] <= position
+    p = jax.nn.softmax(jnp.where(valid, logits, _NEG_INF), axis=-1)
+    out = jnp.einsum("bhl,blhd->bhd", p, v.astype(jnp.float32)).astype(x_t.dtype)
+    return dense(out.reshape(b, 1, -1), params["o_proj"]), {"ckv": ckv, "kpe": kpe}

@@ -66,6 +66,22 @@ def activation(name: str, x: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
+# projections
+# ---------------------------------------------------------------------------
+def dense(x: jax.Array, w) -> jax.Array:
+    """``x @ w`` for a projection weight ``w``: a plain array, or a factored
+    one (``repro.models.lora.Factored``: ``x@W + s·(x@A)@B``, never merged).
+    Where ``w`` is held in a narrower dtype than ``x`` (bf16 weights under a
+    float32 residual stream), ``x`` is cast to it and the product accumulates
+    in float32."""
+    if not isinstance(w, jax.Array) and hasattr(w, "project"):
+        return w.project(x)
+    if x.dtype != w.dtype and jnp.dtype(w.dtype).itemsize < jnp.dtype(x.dtype).itemsize:
+        return jnp.dot(x.astype(w.dtype), w, preferred_element_type=x.dtype)
+    return x @ w
+
+
+# ---------------------------------------------------------------------------
 # MLP (gated / plain)
 # ---------------------------------------------------------------------------
 def init_mlp(rng, d: int, f: int, gated: bool, dtype):
@@ -77,12 +93,12 @@ def init_mlp(rng, d: int, f: int, gated: bool, dtype):
 
 
 def apply_mlp(params, x: jax.Array, act: str) -> jax.Array:
-    h = x @ params["wi"]
+    h = dense(x, params["wi"])
     if "wg" in params:
-        h = activation(act, x @ params["wg"]) * h
+        h = activation(act, dense(x, params["wg"])) * h
     else:
         h = activation(act, h)
-    return h @ params["wo"]
+    return dense(h, params["wo"])
 
 
 # ---------------------------------------------------------------------------
@@ -91,6 +107,49 @@ def apply_mlp(params, x: jax.Array, act: str) -> jax.Array:
 def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
     half = head_dim // 2
     return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention-temperature factor, ``0.1·mscale·ln(factor) + 1``."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(dim: int, theta: float, scaling) -> jax.Array:
+    """YaRN inverse frequencies (``rope_scaling`` type ``yarn``), as
+    DeepSeek-V2's modeling code computes them: the extrapolated
+    frequencies above the correction range, the interpolated ones
+    (``/ factor``) below it, and a linear ramp between."""
+    def correction_dim(rotations):
+        return (dim * math.log(scaling.original_max_position / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    inter = extra / scaling.factor
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                      # 1: extrapolate, 0: interpolate
+    return inter * (1.0 - keep) + extra * keep
+
+
+def apply_rope_yarn(x: jax.Array, positions: jax.Array, theta: float, scaling) -> jax.Array:
+    """RoPE over ``x``'s last dim with YaRN frequencies.  As DeepSeek-V2's
+    ``apply_rotary_pos_emb``, the dims are first de-interleaved (even dims,
+    then odd), then rotated by halves; cos and sin carry YaRN's
+    ``mscale / mscale_all_dim`` factor.  x: (..., S, H, d); positions (..., S)."""
+    d = x.shape[-1]
+    inv = yarn_frequencies(d, theta, scaling)
+    m = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+        scaling.factor, scaling.mscale_all_dim)
+    angles = positions[..., None].astype(jnp.float32) * inv        # (..., S, d/2)
+    cos = (jnp.cos(angles) * m)[..., None, :]
+    sin = (jnp.sin(angles) * m)[..., None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]                           # de-interleave
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:

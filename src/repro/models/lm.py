@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.models import moe as moe_mod
 from repro.models.transformer import TransformerLM
 
 
@@ -46,6 +47,9 @@ class LMClassifier:
     seq_len: int
     remat: bool = True
     name: str = "lm"
+    # score ``accuracy`` over the rows in blocks of this many (0: all at
+    # once), so an eval set need not fit one forward pass
+    eval_batch: int = 0
 
     @property
     def lm(self) -> TransformerLM:
@@ -58,18 +62,42 @@ class LMClassifier:
         # token ids ride in the float32 feature tensor; exact below 2**24
         return x.astype(jnp.int32)
 
-    def loss(self, params, x: jax.Array, y: jax.Array) -> jax.Array:
+    def _batch(self, x: jax.Array, y: jax.Array):
         tokens = self._tokens(x)
         labels = jnp.concatenate(
             [tokens[:, 1:], y[:, None].astype(jnp.int32)], axis=1
         )
-        return self.lm.loss(params, {"tokens": tokens, "labels": labels})
+        return {"tokens": tokens, "labels": labels}
 
-    def accuracy(self, params, x: jax.Array, y: jax.Array) -> jax.Array:
+    def loss(self, params, x: jax.Array, y: jax.Array) -> jax.Array:
+        return self.lm.loss(params, self._batch(x, y))
+
+    @property
+    def expert_counts_shape(self):
+        """(layers, experts) of :meth:`loss_and_counts`' counts of routed
+        assignments where the model has a dropless MoE, else None."""
+        if self.cfg.moe is None or not moe_mod.is_dropless(self.cfg.moe,
+                                                             self.lm.moe_capacity_factor):
+            return None
+        return (self.cfg.num_layers, self.cfg.moe.num_experts)
+
+    def loss_and_counts(self, params, x: jax.Array, y: jax.Array):
+        """``loss`` and each layer's per-expert count of routed assignments."""
+        return self.lm.loss_and_counts(params, self._batch(x, y))
+
+    def _hits(self, params, x: jax.Array, y: jax.Array) -> jax.Array:
         lm = self.lm
         h, _ = lm.hidden(params, {"tokens": self._tokens(x)})
         logits = lm.unembed(params, h[:, -1, :])
-        return jnp.mean((jnp.argmax(logits, axis=-1) == y).astype(jnp.float32))
+        return jnp.sum((jnp.argmax(logits, axis=-1) == y).astype(jnp.float32))
+
+    def accuracy(self, params, x: jax.Array, y: jax.Array) -> jax.Array:
+        n, eb = x.shape[0], self.eval_batch
+        if not eb or n <= eb or n % eb:
+            return self._hits(params, x, y) / n
+        hits = jax.lax.map(lambda xy: self._hits(params, *xy),
+                           (x.reshape(n // eb, eb, *x.shape[1:]), y.reshape(n // eb, eb)))
+        return jnp.sum(hits) / n
 
     def flops_per_sample(self) -> float:
         # 6·N FLOPs/token for fwd+bwd (2N fwd, 4N bwd), active params for MoE

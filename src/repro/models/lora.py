@@ -2,11 +2,21 @@
 
 :class:`LoRAClassifier` wraps any classifier model (MLP/CNN/``LMClassifier``)
 so that **only low-rank adapter factors are trained, aggregated and
-transmitted**: the wrapped model's parameters are frozen closure constants,
-``init`` returns the adapter pytree, and every ``loss``/``accuracy`` call
-evaluates the base model at the merged weights
+transmitted**: the wrapped model's parameters are frozen, ``init`` returns
+the adapter pytree, and every ``loss``/``accuracy`` call evaluates the base
+model with each target weight replaced by its :class:`Factored` form, whose
+projection is computed without merging:
 
-    W_eff = W + scale · A @ B        (A: (..., d_in, r), B: (..., r, d_out))
+    x @ W_eff = x @ W + scale · (x @ A) @ B      (A: (..., d_in, r), B: (..., r, d_out))
+
+The base model's projections take it through ``repro.models.layers.dense``
+(or ``x @ w``).  :meth:`LoRAClassifier.merge` builds
+``W + scale · A @ B`` once, as the deploy and eval artifact.
+
+The frozen base is ``frozen``: the compiled round chunks take it as an
+argument (held once in device memory, never donated) and evaluate
+:meth:`LoRAClassifier.bind` of it, so it is never baked into a program as
+constants.
 
 Because the FL engines derive *everything* from the trained pytree — the
 flat (P, D) round buffer, Eq. 4 aggregation, FLrce's V/A ingest, and the
@@ -39,15 +49,65 @@ Two modes:
 """
 from __future__ import annotations
 
+import copy
 from typing import Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.models.layers import dense
+
 # leaf names treated as low-rank targets: transformer attention/MLP
 # projections (wq/wk/wv/wo/wi/wg) and the dense-layer "w" of the paper
 # MLP/CNN models.  Embedding/unembedding/norm leaves never match.
 DEFAULT_TARGETS = ("wq", "wk", "wv", "wo", "wi", "wg", "w")
+
+
+@jax.tree_util.register_pytree_node_class
+class Factored:
+    """A frozen weight ``w`` (..., d_in, d_out) with its low-rank factors:
+    ``project(x) = x @ w + scale · (x @ a) @ b``.  A factor that is None
+    is the identity (exact mode's square factor).  ``x @ factored`` calls
+    ``project``; a consumer that needs the array itself gets the merged
+    weight through ``__jax_array__``."""
+
+    def __init__(self, w, a, b, scale: float):
+        self.w, self.a, self.b, self.scale = w, a, b, scale
+
+    def tree_flatten(self):
+        return (self.w, self.a, self.b), self.scale
+
+    @classmethod
+    def tree_unflatten(cls, scale, children):
+        return cls(*children, scale)
+
+    @property
+    def shape(self):
+        return self.w.shape
+
+    @property
+    def dtype(self):
+        return self.w.dtype
+
+    def project(self, x: jax.Array) -> jax.Array:
+        base = dense(x, self.w)
+        low = x.astype(jnp.float32)
+        if self.a is not None:
+            low = low @ self.a
+        if self.b is not None:
+            low = low @ self.b
+        return base + (self.scale * low).astype(base.dtype)
+
+    def __rmatmul__(self, x):
+        return self.project(x)
+
+    def merged(self) -> jax.Array:
+        a, b = self.a, self.b
+        delta = a if b is None else (b if a is None else jnp.matmul(a, b))
+        return (self.w.astype(jnp.float32) + self.scale * delta).astype(self.w.dtype)
+
+    def __jax_array__(self):
+        return self.merged()
 
 
 def _path_str(path) -> str:
@@ -71,7 +131,9 @@ class LoRAClassifier:
                  targets: Sequence[str] = DEFAULT_TARGETS,
                  exact: bool = False, train_rest: bool = False):
         self.base = base
-        self.base_params = jax.tree_util.tree_map(jnp.asarray, base_params)
+        # arrays (or abstract shapes, for planning a compile)
+        self.base_params = jax.tree_util.tree_map(
+            lambda l: l if isinstance(l, jax.ShapeDtypeStruct) else jnp.asarray(l), base_params)
         self.rank = int(rank)
         self.scale = float(scale)
         self.targets = tuple(targets)
@@ -157,34 +219,52 @@ class LoRAClassifier:
                 adapters[key] = leaf
         return adapters
 
-    def merge(self, adapters: Dict) -> object:
-        """Base params with every adapter folded in: the full-model pytree
-        the wrapped model evaluates (and the eval/deploy artifact)."""
+    def factored(self, adapters: Dict) -> object:
+        """The base params with every target weight in its :class:`Factored`
+        form (and the trained rest leaves in place): the pytree the wrapped
+        model evaluates."""
         leaves = jax.tree_util.tree_flatten_with_path(self.base_params)[0]
-        merged = []
-        for (key, kind, shape), (_, leaf) in zip(self._plan, leaves):
+        out = []
+        for (key, kind, _), (_, leaf) in zip(self._plan, leaves):
             if kind == "target":
                 ab = adapters[key]
-                *_, d_in, d_out = shape
-                if self.exact:
-                    r = self._target_rank(d_in, d_out)
-                    a = ab.get("a", jnp.eye(r, dtype=jnp.float32))
-                    b = ab.get("b", jnp.eye(r, dtype=jnp.float32))
-                else:
-                    a, b = ab["a"], ab["b"]
-                delta = self.scale * jnp.matmul(a, b)
-                merged.append((leaf.astype(jnp.float32) + delta).astype(leaf.dtype))
+                out.append(Factored(leaf, ab.get("a"), ab.get("b"), self.scale))
             elif self.train_rest:
-                merged.append(adapters[key])
+                out.append(adapters[key])
             else:
-                merged.append(leaf)
-        return jax.tree_util.tree_unflatten(self._treedef, merged)
+                out.append(leaf)
+        return jax.tree_util.tree_unflatten(self._treedef, out)
+
+    def merge(self, adapters: Dict) -> object:
+        """Base params with every adapter folded in (``W + scale·A@B``): the
+        deploy and eval artifact."""
+        return jax.tree_util.tree_map(
+            lambda l: l.merged() if isinstance(l, Factored) else l,
+            self.factored(adapters), is_leaf=lambda l: isinstance(l, Factored))
+
+    @property
+    def frozen(self):
+        """The frozen base, which the compiled chunks take as an argument."""
+        return self.base_params
+
+    def bind(self, frozen) -> "LoRAClassifier":
+        """This model over ``frozen`` (the chunk's traced base argument)."""
+        view = copy.copy(self)
+        view.base_params = frozen
+        return view
+
+    @property
+    def expert_counts_shape(self):
+        return getattr(self.base, "expert_counts_shape", None)
 
     def loss(self, params, x: jax.Array, y: jax.Array) -> jax.Array:
-        return self.base.loss(self.merge(params), x, y)
+        return self.base.loss(self.factored(params), x, y)
+
+    def loss_and_counts(self, params, x: jax.Array, y: jax.Array):
+        return self.base.loss_and_counts(self.factored(params), x, y)
 
     def accuracy(self, params, x: jax.Array, y: jax.Array) -> jax.Array:
-        return self.base.accuracy(self.merge(params), x, y)
+        return self.base.accuracy(self.factored(params), x, y)
 
     def flops_per_sample(self) -> float:
         # training still runs fwd+bwd through the full base model; the

@@ -1,4 +1,5 @@
-"""Mixture-of-Experts FFN with top-k routing and capacity-based dispatch.
+"""Mixture-of-Experts FFN with top-k routing: capacity-based dispatch, or
+dropless grouped expert products.
 
 Dispatch follows the Switch/Mesh-TF formulation: tokens are routed to experts
 through dense one-hot dispatch/combine tensors, which (a) keeps everything
@@ -8,6 +9,17 @@ per-expert token buffer; overflowing tokens are dropped (residual passes
 through), exactly as in production MoE trainers.
 
 A Switch-style load-balance auxiliary loss is returned alongside the output.
+
+The dropless path (:func:`apply_moe_dropless`; see :func:`is_dropless`)
+drops no token at any load: the (token, expert) pairs are sorted by expert
+and each expert's SwiGLU runs as grouped products over the experts' token
+counts (``repro.kernels.moe_gmm``), then the pairs are un-permuted and
+combined with their gates.  Shared experts (DeepSeek) are one MLP added to
+every token.  The backward pass computes dX through the transposed grouped
+products and the gates' gradient; a gradient of the expert weights only
+where they train (through ``lax.ragged_dot``'s transpose), never for frozen
+experts (a LoRA run).  Under ``vmap`` (the cohort's clients and their
+examples) the mapped tokens join one grouped product per layer.
 """
 from __future__ import annotations
 
@@ -15,25 +27,41 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.configs.base import ArchConfig
-from repro.models.layers import activation, dense_init
+from repro.kernels import moe_gmm
+from repro.kernels.ref import moe_gmm_ref
+from repro.models.layers import activation, apply_mlp, dense_init, init_mlp
 
 
 def init_moe(rng, cfg: ArchConfig, dtype) -> Dict:
     assert cfg.moe is not None
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.moe.num_experts
+    d, e = cfg.d_model, cfg.moe.num_experts
+    f = cfg.moe.expert_width(cfg.d_ff)
     rr, ri, rg, ro = jax.random.split(rng, 4)
+
+    def experts(key, fan_in, fan_out):
+        return jax.vmap(lambda i: dense_init(jax.random.fold_in(key, i), fan_in, fan_out, dtype))(
+            jnp.arange(e))
+
     params = {
         "router": dense_init(rr, d, e, jnp.float32),  # router always fp32
-        "wi": jnp.stack([dense_init(jax.random.fold_in(ri, i), d, f, dtype) for i in range(e)]),
-        "wo": jnp.stack([dense_init(jax.random.fold_in(ro, i), f, d, dtype) for i in range(e)]),
+        "wi": experts(ri, d, f),
+        "wo": experts(ro, f, d),
     }
     if cfg.gated_mlp:
-        params["wg"] = jnp.stack(
-            [dense_init(jax.random.fold_in(rg, i), d, f, dtype) for i in range(e)]
-        )
+        params["wg"] = experts(rg, d, f)
+    if cfg.moe.num_shared_experts:
+        params["shared"] = init_mlp(jax.random.fold_in(rr, 1), d,
+                                    cfg.moe.num_shared_experts * f, cfg.gated_mlp, dtype)
     return params
+
+
+def is_dropless(moe, capacity_factor: float | None) -> bool:
+    """Whether a MoE layer runs the dropless path: a config that asks for it
+    on every pass, or a call with no capacity (decode)."""
+    return moe.dropless or capacity_factor is None
 
 
 def _pin(x: jax.Array, spec_dims) -> jax.Array:
@@ -54,9 +82,9 @@ def apply_moe(
 ) -> Tuple[jax.Array, jax.Array]:
     """Returns (output (B,S,D), aux load-balance loss scalar).
 
-    ``capacity_factor=None`` disables token dropping (capacity = N tokens) —
-    used on the decode path, where per-step load balance is meaningless and a
-    dropped token would corrupt generation.
+    ``capacity_factor=None`` (the decode path, where a dropped token would
+    corrupt generation) and ``MoEConfig.dropless`` run
+    :func:`apply_moe_dropless`, which drops no token.
 
     ``group_size`` (beyond-paper §Perf optimization): dispatch within groups
     of G tokens instead of over all N.  The dense one-hot dispatch einsum
@@ -65,6 +93,9 @@ def apply_moe(
     G = 2 048 it is a few percent.  Capacity is enforced per group, exactly
     the Switch/Mesh-TF formulation."""
     moe = cfg.moe
+    if is_dropless(moe, capacity_factor):
+        out, aux, _ = apply_moe_dropless(params, x, cfg)
+        return out, aux
     b, s, d = x.shape
     e, k = moe.num_experts, moe.top_k
     n = b * s
@@ -80,10 +111,7 @@ def apply_moe(
     pad = (-n) % g
     n_pad = n + pad
     ng = n_pad // g
-    if capacity_factor is None:
-        capacity = g  # drop-free within each group
-    else:
-        capacity = max(1, int(capacity_factor * g * k / e))
+    capacity = max(1, int(capacity_factor * g * k / e))
 
     onehot = jax.nn.one_hot(expert_ids, e, dtype=jnp.int32)     # (N, k, E)
     if pad:
@@ -139,3 +167,200 @@ def apply_moe(
     aux = e * jnp.sum(token_frac * prob_frac) * moe.aux_loss_weight
 
     return out.reshape(b, s, d), aux
+
+
+# ---------------------------------------------------------------------------
+# dropless: sorted (token, expert) pairs through grouped expert products
+# ---------------------------------------------------------------------------
+def route(router: jax.Array, xt: jax.Array, moe) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Gates (N, k) and expert ids (N, k) of tokens ``xt`` (N, D), and the
+    softmax probabilities (N, E): float32 logits at ``HIGHEST`` (DeepSeek
+    computes its gate in float32), greedy top-k, renormalised or not, times
+    the routed scale."""
+    logits = jnp.dot(xt.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, ids = lax.top_k(probs, moe.top_k)
+    if moe.norm_topk_prob:
+        gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+    return gates * moe.routed_scaling_factor, ids, probs
+
+
+def _dispatch(x, ids, num_experts):
+    """Sort the (token, choice) pairs by expert: the sort order, each sorted
+    pair's token, the experts' counts (the last one holding the pad rows)
+    and the sorted tokens, zero-padded to a whole number of m tiles."""
+    n, k = ids.shape
+    m = n * k
+    m_pad = moe_gmm.padded_rows(m)
+    flat = ids.reshape(m)
+    order = jnp.argsort(flat, stable=True)
+    token = order // k
+    counts = jnp.zeros((num_experts,), jnp.int32).at[flat].add(1)
+    group_sizes = counts.at[num_experts - 1].add(m_pad - m)
+    xs = jnp.pad(x[token], ((0, m_pad - m), (0, 0)))
+    return order, token, group_sizes, xs
+
+
+def _expert_inputs(x, ids, w_gate, w_up, act, product=moe_gmm.gmm):
+    """The sorted pairs' gate and up products, and their SwiGLU."""
+    dt = w_up.dtype
+    with jax.named_scope("moe/dispatch"):
+        order, token, group_sizes, xs = _dispatch(x.astype(dt), ids, w_up.shape[0])
+    with jax.named_scope("moe/experts"):
+        g = product(xs, w_gate, group_sizes)
+        u = product(xs, w_up, group_sizes)
+        h = activation(act, g) * u
+    return order, token, group_sizes, g, u, h
+
+
+def _experts_fwd_impl(x, ids, gates, w_gate, w_up, w_down, act, product=moe_gmm.gmm):
+    n, k = ids.shape
+    m = n * k
+    order, _, group_sizes, _, _, h = _expert_inputs(x, ids, w_gate, w_up, act, product)
+    with jax.named_scope("moe/experts"):
+        y = product(h.astype(w_down.dtype), w_down, group_sizes)[:m]   # (m, D)
+    with jax.named_scope("moe/combine"):
+        gs = gates.reshape(m)[order].astype(jnp.float32)
+        out = jnp.sum((y * gs[:, None])[jnp.argsort(order)].reshape(n, k, -1), axis=1)
+    return out.astype(x.dtype)
+
+
+def _experts_bwd_impl(x, ids, gates, w_gate, w_up, w_down, d_out, act):
+    """dX and the gates' cotangent.  The sorted pairs' gate and up products
+    are recomputed (the forward keeps only its inputs), and
+    ``d_gate = <dy, h·W_down> = <dy·W_downᵀ, h>`` needs no down product."""
+    n, k = ids.shape
+    m = n * k
+    dt = w_up.dtype
+    order, token, group_sizes, g, u, h = _expert_inputs(x, ids, w_gate, w_up, act)
+    inv = jnp.argsort(order)
+    with jax.named_scope("moe/combine"):
+        dy = jnp.pad(d_out.astype(dt)[token], ((0, g.shape[0] - m), (0, 0)))
+        gs = jnp.pad(gates.reshape(m)[order].astype(jnp.float32), (0, g.shape[0] - m))
+    with jax.named_scope("moe/experts"):
+        dh0 = moe_gmm.gmm(dy, w_down, group_sizes, transpose_rhs=True)     # dy·W_downᵀ
+        d_gates = jnp.sum(dh0 * h, axis=-1)[:m][inv]
+        dg, du = jax.vjp(lambda a, b: activation(act, a) * b, g, u)[1](dh0 * gs[:, None])
+        dxs = (moe_gmm.gmm(dg.astype(dt), w_gate, group_sizes, transpose_rhs=True)
+               + moe_gmm.gmm(du.astype(dt), w_up, group_sizes, transpose_rhs=True))
+    with jax.named_scope("moe/dispatch"):
+        dx = jnp.sum(dxs[:m][inv].reshape(n, k, -1), axis=1)
+    return dx.astype(x.dtype), d_gates.reshape(n, k).astype(gates.dtype)
+
+
+def _fold(axis_size, in_batched, *args):
+    """Fold a vmapped axis into the token axis: each batched (V, N, ...) arg
+    becomes (V·N, ...); an unbatched one is broadcast first."""
+    return [a.reshape(axis_size * a.shape[1], *a.shape[2:]) if bat
+            else jnp.broadcast_to(a, (axis_size, *a.shape)).reshape(-1, *a.shape[1:])
+            for a, bat in zip(args, in_batched)]
+
+
+def _unfold(axis_size, *outs):
+    return tuple(o.reshape(axis_size, -1, *o.shape[1:]) for o in outs)
+
+
+def _token_vmap(impl, n_tokens: int):
+    """``impl`` (its first ``n_tokens`` args token-major, the rest the
+    expert weights) as a ``custom_vmap``: a mapped call folds the mapped
+    axis into the tokens and runs as one call."""
+    fn = jax.custom_batching.custom_vmap(impl)
+
+    @fn.def_vmap
+    def rule(axis_size, in_batched, *args):
+        if any(in_batched[n_tokens:]):
+            raise NotImplementedError("per-client expert weights under vmap")
+        folded = _fold(axis_size, in_batched[:n_tokens], *args[:n_tokens])
+        out = fn(*folded, *args[n_tokens:])
+        outs = out if isinstance(out, tuple) else (out,)
+        unfolded = _unfold(axis_size, *outs)
+        batched = tuple(True for _ in outs)
+        return (unfolded, batched) if isinstance(out, tuple) else (unfolded[0], True)
+
+    return fn
+
+
+def _weight_grads(act: str):
+    """The expert weights' cotangents ``(x, ids, gates, d_out, w_gate, w_up,
+    w_down) -> (dW_gate, dW_up, dW_down)``, through ``lax.ragged_dot``'s
+    transpose.  A mapped call (each instance its own cotangent of shared
+    weights) runs its instances one at a time."""
+    def impl(x, ids, gates, d_out, w_gate, w_up, w_down):
+        fwd = lambda *w: _experts_fwd_impl(x, ids, gates, *w, act, product=moe_gmm_ref)
+        return jax.vjp(fwd, w_gate, w_up, w_down)[1](d_out)
+
+    fn = jax.custom_batching.custom_vmap(impl)
+
+    @fn.def_vmap
+    def rule(axis_size, in_batched, *args):
+        if any(in_batched[4:]):
+            raise NotImplementedError("per-client expert weights under vmap")
+        tokens = [a if bat else jnp.broadcast_to(a, (axis_size, *a.shape))
+                  for a, bat in zip(args[:4], in_batched[:4])]
+        return lax.map(lambda t: fn(*t, *args[4:]), tuple(tokens)), (True, True, True)
+
+    return fn
+
+
+_EXPERTS = {}
+
+
+def routed_experts(x, ids, gates, w_gate, w_up, w_down, act: str = "silu"):
+    """Σ_j gates[t, j] · SwiGLU_{ids[t, j]}(x[t]) over every (token, choice)
+    pair, as sorted grouped products: no capacity, no dropped token.  The
+    backward pass computes the expert weights' cotangent only where they
+    are differentiated (``symbolic_zeros``): frozen experts cost none."""
+    if act not in _EXPERTS:
+        fwd = _token_vmap(lambda x, i, g, wg, wu, wd: _experts_fwd_impl(x, i, g, wg, wu, wd, act), 3)
+        bwd = _token_vmap(
+            lambda x, i, g, d, wg, wu, wd: _experts_bwd_impl(x, i, g, wg, wu, wd, d, act), 4)
+        weight_grads = _weight_grads(act)
+
+        @jax.custom_vjp
+        def experts(x, ids, gates, w_gate, w_up, w_down):
+            return fwd(x, ids, gates, w_gate, w_up, w_down)
+
+        def experts_fwd(*primals):
+            args = tuple(p.value for p in primals)
+            # whether the experts train rides in the residuals' structure
+            trained = () if any(p.perturbed for p in primals[3:]) else None
+            return fwd(*args), (args, trained)
+
+        def experts_bwd(saved, d_out):
+            (x, ids, gates, w_gate, w_up, w_down), trained = saved
+            if isinstance(d_out, jax.custom_derivatives.SymbolicZero):
+                return (None,) * 6
+            dx, d_gates = bwd(x, ids, gates, d_out, w_gate, w_up, w_down)
+            dw = (None,) * 3
+            if trained is not None:
+                dw = weight_grads(x, ids, gates, d_out, w_gate, w_up, w_down)
+            return (dx, None, d_gates, *dw)
+
+        experts.defvjp(experts_fwd, experts_bwd, symbolic_zeros=True)
+        _EXPERTS[act] = experts
+    return _EXPERTS[act](x, ids, gates, w_gate, w_up, w_down)
+
+
+def apply_moe_dropless(params: Dict, x: jax.Array, cfg: ArchConfig):
+    """Dropless MoE over (B, S, D): returns the output, the Switch aux loss
+    (times ``aux_loss_weight``) and the per-expert count of routed
+    assignments (E,) float32."""
+    moe = cfg.moe
+    if "wg" not in params:
+        raise NotImplementedError("the dropless MoE path runs gated (SwiGLU) experts")
+    b, s, d = x.shape
+    e = moe.num_experts
+    xt = x.reshape(b * s, d)
+    with jax.named_scope("moe/route"):
+        gates, ids, probs = route(params["router"], xt, moe)
+        counts = jnp.zeros((e,), jnp.float32).at[ids.reshape(-1)].add(1.0)
+    out = routed_experts(xt, ids, gates, params["wg"], params["wi"], params["wo"], cfg.act)
+    if "shared" in params:
+        with jax.named_scope("moe/shared"):
+            out = out + apply_mlp(params["shared"], xt, cfg.act)
+    aux = jnp.zeros((), jnp.float32)
+    if moe.aux_loss_weight:
+        token_frac = counts / (b * s)
+        aux = e * jnp.sum(token_frac * jnp.mean(probs, axis=0)) * moe.aux_loss_weight
+    return out.reshape(b, s, d), aux, counts

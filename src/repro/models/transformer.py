@@ -9,6 +9,8 @@ Layers that do not fill a whole cycle ("rest") run unscanned.
 
 Params layout:
     embed                (V, D)
+    dense                leading dense layers (cfg.first_dense_layers; only
+                         present when there are any), unscanned
     cycles               list over pattern positions; leaves stacked (NC, ...)
     rest                 list of per-layer params (len = num_layers % len(pattern))
     final_norm
@@ -28,6 +30,7 @@ from repro.configs.base import (
     ATTN_CROSS,
     ATTN_GLOBAL,
     ATTN_LOCAL,
+    ATTN_MLA,
     MLSTM,
     RGLRU,
     SLSTM,
@@ -37,11 +40,11 @@ from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
 from repro.models import ssm as ssm_mod
-from repro.models.layers import apply_mlp, apply_norm, embed_init, init_mlp, init_norm
+from repro.models.layers import apply_mlp, apply_norm, dense, embed_init, init_mlp, init_norm
 
 PyTree = Any
 
-_ATTN_KINDS = (ATTN_GLOBAL, ATTN_LOCAL, ATTN_CROSS)
+_ATTN_KINDS = (ATTN_GLOBAL, ATTN_LOCAL, ATTN_CROSS, ATTN_MLA)
 
 
 def _pin_spec(x: jax.Array, batch_axes, spec_tail) -> jax.Array:
@@ -85,10 +88,15 @@ def _has_mlp(kind: str, cfg: ArchConfig) -> bool:
     return kind in _ATTN_KINDS and cfg.d_ff > 0
 
 
-def init_block(rng, kind: str, cfg: ArchConfig, dtype, *, decoder_cross: bool = False) -> Dict:
+def init_block(rng, kind: str, cfg: ArchConfig, dtype, *, decoder_cross: bool = False,
+               dense_mlp: bool = False) -> Dict:
+    """One block's params; ``dense_mlp`` gives an MoE config's leading dense
+    layer its dense MLP (width ``d_ff``)."""
     r1, r2, r3, r4, r5 = jax.random.split(rng, 5)
     p: Dict = {"norm1": init_norm(cfg.norm, cfg.d_model, dtype)}
-    if kind in _ATTN_KINDS:
+    if kind == ATTN_MLA:
+        p["mixer"] = attn.init_mla(r1, cfg, dtype)
+    elif kind in _ATTN_KINDS:
         p["mixer"] = attn.init_attention(r1, cfg, dtype)
     elif kind == MLSTM:
         p["mixer"] = ssm_mod.init_mlstm(r1, cfg, dtype)
@@ -103,11 +111,15 @@ def init_block(rng, kind: str, cfg: ArchConfig, dtype, *, decoder_cross: bool = 
         p["cross"] = attn.init_attention(r4, cfg, dtype, cross=True)
     if _has_mlp(kind, cfg):
         p["norm2"] = init_norm(cfg.norm, cfg.d_model, dtype)
-        if cfg.moe is not None:
+        if cfg.moe is not None and not dense_mlp:
             p["mlp"] = moe_mod.init_moe(r3, cfg, dtype)
         else:
             p["mlp"] = init_mlp(r3, cfg.d_model, cfg.d_ff, cfg.gated_mlp, dtype)
     return p
+
+
+def _is_moe(mlp_params) -> bool:
+    return "router" in mlp_params
 
 
 def apply_block_train(
@@ -125,11 +137,17 @@ def apply_block_train(
     moe_expert_axis=None,
     mlstm_chunk: int = 256,
     mlstm_inner_axis=None,
-) -> Tuple[jax.Array, jax.Array]:
-    """Pre-norm residual block.  Returns (x, moe aux loss)."""
+    with_counts: bool = False,
+):
+    """Pre-norm residual block.  Returns (x, moe aux loss), and with
+    ``with_counts`` the block's per-expert count of routed assignments
+    (E,) (zeros where the block has no dropless MoE)."""
     aux = jnp.zeros((), jnp.float32)
+    counts = jnp.zeros((cfg.moe.num_experts if cfg.moe else 0,), jnp.float32)
     h = apply_norm(cfg.norm, params["norm1"], x)
-    if kind in _ATTN_KINDS:
+    if kind == ATTN_MLA:
+        mix = attn.mla_block(params["mixer"], h, positions, cfg)
+    elif kind in _ATTN_KINDS:
         if causal:
             mix = attn.attention_block(params["mixer"], h, positions, cfg, local=(kind == ATTN_LOCAL))
         else:  # encoder self-attention (bidirectional)
@@ -156,20 +174,24 @@ def apply_block_train(
         )
     if "mlp" in params:
         h2 = apply_norm(cfg.norm, params["norm2"], x)
-        if cfg.moe is not None:
+        if not _is_moe(params["mlp"]):
+            mlp_out = apply_mlp(params["mlp"], h2, cfg.act)
+        elif moe_mod.is_dropless(cfg.moe, moe_capacity_factor):
+            mlp_out, aux, counts = moe_mod.apply_moe_dropless(params["mlp"], h2, cfg)
+        else:
             mlp_out, aux = moe_mod.apply_moe(
                 params["mlp"], h2, cfg, capacity_factor=moe_capacity_factor,
                 group_size=moe_group_size, batch_axes=batch_axes,
                 expert_axis=moe_expert_axis,
             )
-        else:
-            mlp_out = apply_mlp(params["mlp"], h2, cfg.act)
         x = x + mlp_out
-    return x, aux
+    return (x, aux, counts) if with_counts else (x, aux)
 
 
 # --- caches ----------------------------------------------------------------
 def init_block_cache(kind: str, cfg: ArchConfig, batch: int, cache_len: int, dtype) -> Dict:
+    if kind == ATTN_MLA:
+        return attn.init_mla_cache(cfg, batch, cache_len, dtype)
     if kind in _ATTN_KINDS:
         length = min(cache_len, cfg.window) if (kind == ATTN_LOCAL and cfg.window) else cache_len
         return attn.init_kv_cache(cfg, batch, length, dtype)
@@ -193,7 +215,9 @@ def apply_block_decode(
     cross_kv: Optional[Tuple[jax.Array, jax.Array]] = None,
 ) -> Tuple[jax.Array, Dict]:
     h = apply_norm(cfg.norm, params["norm1"], x_t)
-    if kind in _ATTN_KINDS:
+    if kind == ATTN_MLA:
+        mix, new_cache = attn.mla_decode_step(params["mixer"], h, cache, position, cfg)
+    elif kind in _ATTN_KINDS:
         mix, new_cache = attn.attention_decode_step(
             params["mixer"], h, cache, position, cfg, local=(kind == ATTN_LOCAL)
         )
@@ -214,7 +238,7 @@ def apply_block_decode(
         x_t = x_t + out
     if "mlp" in params:
         h2 = apply_norm(cfg.norm, params["norm2"], x_t)
-        if cfg.moe is not None:
+        if _is_moe(params["mlp"]):
             mlp_out, _ = moe_mod.apply_moe(params["mlp"], h2, cfg, capacity_factor=None)
         else:
             mlp_out = apply_mlp(params["mlp"], h2, cfg.act)
@@ -230,7 +254,14 @@ def _cycle_layout(num_layers: int, pattern: Tuple[str, ...]) -> Tuple[int, int]:
     return num_layers // plen, num_layers % plen
 
 
-def _init_stack(rng, cfg: ArchConfig, dtype, *, pattern, num_layers, decoder_cross=False) -> Dict:
+def _init_stack(rng, cfg: ArchConfig, dtype, *, pattern, num_layers, decoder_cross=False,
+                first_dense: int = 0) -> Dict:
+    dense_params = [
+        init_block(jax.random.fold_in(rng, 98_000 + i), pattern[i % len(pattern)], cfg, dtype,
+                   decoder_cross=decoder_cross, dense_mlp=True)
+        for i in range(first_dense)
+    ]
+    num_layers -= first_dense
     nc, rest = _cycle_layout(num_layers, pattern)
     cycles: List[PyTree] = []
     for pos, kind in enumerate(pattern):
@@ -253,7 +284,10 @@ def _init_stack(rng, cfg: ArchConfig, dtype, *, pattern, num_layers, decoder_cro
         )
         for i in range(rest)
     ]
-    return {"cycles": cycles, "rest": rest_params}
+    out = {"cycles": cycles, "rest": rest_params}
+    if first_dense:
+        out["dense"] = dense_params
+    return out
 
 
 def _apply_stack_train(
@@ -274,46 +308,63 @@ def _apply_stack_train(
     mlstm_inner_axis=None,
     seq_axis=None,
     seq_axis_size=0,
-) -> Tuple[jax.Array, jax.Array]:
+    with_counts: bool = False,
+):
+    """Returns (x, aux loss), and with ``with_counts`` each layer's
+    per-expert count of routed assignments, (num_layers, E), in the order
+    the layers run (leading dense layers, scanned cycles, rest)."""
     aux_total = jnp.zeros((), jnp.float32)
+    counts = []
+
+    def block(pp, hh, kind):
+        return apply_block_train(
+            pp, kind, hh, positions, cfg, encoder_out=encoder_out, causal=causal,
+            moe_capacity_factor=moe_capacity_factor,
+            moe_group_size=moe_group_size, batch_axes=batch_axes,
+            moe_expert_axis=moe_expert_axis, mlstm_chunk=mlstm_chunk,
+            mlstm_inner_axis=mlstm_inner_axis, with_counts=with_counts,
+        )
+
+    def unscanned(p, kind):
+        nonlocal x, aux_total
+        blk = functools.partial(block, kind=kind)
+        out = (jax.checkpoint(blk) if remat else blk)(p, x)
+        x, aux_total = out[0], aux_total + out[1]
+        if with_counts:
+            counts.append(out[2][None])
+
+    for i, p in enumerate(stack.get("dense", ())):
+        unscanned(p, pattern[i % len(pattern)])
 
     def cycle_body(carry, cycle_params):
         h, aux = carry
         h = _pin_batch(h, batch_axes, seq_axis, seq_axis_size)
+        cycle_counts = []
         for pos, kind in enumerate(pattern):
-            h, a = apply_block_train(
-                cycle_params[pos], kind, h, positions, cfg,
-                encoder_out=encoder_out, causal=causal,
-                moe_capacity_factor=moe_capacity_factor,
-                moe_group_size=moe_group_size, batch_axes=batch_axes,
-                moe_expert_axis=moe_expert_axis, mlstm_chunk=mlstm_chunk,
-                mlstm_inner_axis=mlstm_inner_axis,
-            )
-            h = _pin_batch(h, batch_axes, seq_axis, seq_axis_size)
-            aux = aux + a
-        return (h, aux), None
+            out = block(cycle_params[pos], h, kind)
+            h = _pin_batch(out[0], batch_axes, seq_axis, seq_axis_size)
+            aux = aux + out[1]
+            if with_counts:
+                cycle_counts.append(out[2])
+        return (h, aux), (jnp.stack(cycle_counts) if with_counts else None)
 
     body = jax.checkpoint(cycle_body) if remat else cycle_body
     if stack["cycles"] and stack["cycles"][0] is not None:
-        (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), tuple(stack["cycles"]))
+        (x, aux_total), ys = jax.lax.scan(body, (x, aux_total), tuple(stack["cycles"]))
+        if with_counts:
+            counts.append(ys.reshape(-1, ys.shape[-1]))
     for i, p in enumerate(stack["rest"]):
-        def rest_block(pp, hh, _kind=pattern[i]):
-            return apply_block_train(
-                pp, _kind, hh, positions, cfg, encoder_out=encoder_out, causal=causal,
-                moe_capacity_factor=moe_capacity_factor,
-                moe_group_size=moe_group_size, batch_axes=batch_axes,
-                moe_expert_axis=moe_expert_axis, mlstm_chunk=mlstm_chunk,
-                mlstm_inner_axis=mlstm_inner_axis,
-            )
-
-        blk = jax.checkpoint(rest_block) if remat else rest_block
-        x, a = blk(p, x)
-        aux_total = aux_total + a
+        unscanned(p, pattern[i])
+    if with_counts:
+        return x, aux_total, jnp.concatenate(counts)
     return x, aux_total
 
 
-def _init_stack_cache(cfg: ArchConfig, batch: int, cache_len: int, dtype, *, pattern, num_layers) -> Dict:
-    nc, rest = _cycle_layout(num_layers, pattern)
+def _init_stack_cache(cfg: ArchConfig, batch: int, cache_len: int, dtype, *, pattern, num_layers,
+                      first_dense: int = 0) -> Dict:
+    dense_caches = [init_block_cache(pattern[i % len(pattern)], cfg, batch, cache_len, dtype)
+                    for i in range(first_dense)]
+    nc, rest = _cycle_layout(num_layers - first_dense, pattern)
     cycles = []
     for pos, kind in enumerate(pattern):
         per_cycle = [init_block_cache(kind, cfg, batch, cache_len, dtype) for _ in range(nc)]
@@ -321,7 +372,10 @@ def _init_stack_cache(cfg: ArchConfig, batch: int, cache_len: int, dtype, *, pat
             jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *per_cycle) if nc else None
         )
     rest_caches = [init_block_cache(pattern[i], cfg, batch, cache_len, dtype) for i in range(rest)]
-    return {"cycles": cycles, "rest": rest_caches}
+    out = {"cycles": cycles, "rest": rest_caches}
+    if first_dense:
+        out["dense"] = dense_caches
+    return out
 
 
 def _apply_stack_decode(
@@ -339,6 +393,13 @@ def _apply_stack_decode(
     # placeholders of a cycle-less stack (NC=0) survive and the returned cache
     # treedef always matches init_cache's
     new_caches = {"cycles": list(caches["cycles"]), "rest": []}
+    if "dense" in stack:
+        new_caches["dense"] = []
+        for i, p in enumerate(stack["dense"]):
+            x_t, nc_ = apply_block_decode(
+                p, pattern[i % len(pattern)], x_t, caches["dense"][i], position, cfg
+            )
+            new_caches["dense"].append(nc_)
 
     if stack["cycles"] and stack["cycles"][0] is not None:
         have_cross = cross_kv is not None
@@ -408,6 +469,12 @@ class TransformerLM:
     def dtype(self):
         return jnp.dtype(self.cfg.dtype)
 
+    @property
+    def act_dtype(self):
+        """The residual stream's dtype (``cfg.residual_dtype``, else the
+        weights')."""
+        return jnp.dtype(self.cfg.residual_dtype or self.cfg.dtype)
+
     # -- params -------------------------------------------------------------
     def init(self, rng: jax.Array) -> PyTree:
         cfg = self.cfg
@@ -416,7 +483,7 @@ class TransformerLM:
         params: Dict = {"embed": embed_init(r_emb, cfg.vocab_size, cfg.d_model, dtype)}
         params["decoder"] = _init_stack(
             r_dec, cfg, dtype, pattern=cfg.pattern, num_layers=cfg.num_layers,
-            decoder_cross=cfg.is_encdec,
+            decoder_cross=cfg.is_encdec, first_dense=cfg.first_dense_layers,
         )
         params["final_norm"] = init_norm(cfg.norm, cfg.d_model, dtype)
         if not cfg.tie_embeddings:
@@ -443,21 +510,23 @@ class TransformerLM:
         return apply_norm(cfg.norm, params["encoder_norm"], h)
 
     # -- full-sequence forward (train / prefill) -----------------------------
-    def hidden(self, params: PyTree, batch: Dict[str, jax.Array]) -> Tuple[jax.Array, jax.Array]:
-        """Final-norm hidden states (B, S_total, D) + moe aux loss."""
+    def hidden(self, params: PyTree, batch: Dict[str, jax.Array], *, with_counts: bool = False):
+        """Final-norm hidden states (B, S_total, D) + moe aux loss, and with
+        ``with_counts`` each decoder layer's per-expert count of routed
+        assignments (num_layers, E)."""
         cfg = self.cfg
         tokens = batch["tokens"]
         b = tokens.shape[0]
-        h = params["embed"][tokens].astype(self.dtype)
+        h = params["embed"][tokens].astype(self.act_dtype)
         if cfg.image_tokens and "image_emb" in batch:
-            h = jnp.concatenate([batch["image_emb"].astype(self.dtype), h], axis=1)
+            h = jnp.concatenate([batch["image_emb"].astype(self.act_dtype), h], axis=1)
         h = _pin_batch(h, self.batch_axes)
         s = h.shape[1]
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
         encoder_out = None
         if cfg.is_encdec:
             encoder_out = self.encode(params, batch["frames"])
-        h, aux = _apply_stack_train(
+        out = _apply_stack_train(
             params["decoder"], h, positions, cfg,
             pattern=cfg.pattern, causal=True, encoder_out=encoder_out, remat=self.remat,
             moe_capacity_factor=self.moe_capacity_factor,
@@ -465,9 +534,11 @@ class TransformerLM:
             moe_expert_axis=self.moe_expert_axis, mlstm_chunk=self.mlstm_chunk,
             mlstm_inner_axis=self.mlstm_inner_axis,
             seq_axis=self.seq_axis, seq_axis_size=self.seq_axis_size,
+            with_counts=with_counts,
         )
-        h = apply_norm(cfg.norm, params["final_norm"], h)
-        return _pin_batch(h, self.batch_axes, self.seq_axis, self.seq_axis_size), aux
+        h = apply_norm(cfg.norm, params["final_norm"], out[0])
+        h = _pin_batch(h, self.batch_axes, self.seq_axis, self.seq_axis_size)
+        return (h, *out[1:])
 
     def forward(self, params: PyTree, batch: Dict[str, jax.Array]) -> Tuple[jax.Array, jax.Array]:
         """Returns (logits (B, S, V), moe aux loss).  Materializes full logits —
@@ -480,8 +551,8 @@ class TransformerLM:
 
     def unembed(self, params: PyTree, h: jax.Array) -> jax.Array:
         if self.cfg.tie_embeddings:
-            return h @ params["embed"].T
-        return h @ params["unembed"]
+            return dense(h, params["embed"].T)
+        return dense(h, params["unembed"])
 
     # -- loss ---------------------------------------------------------------
     def loss(self, params: PyTree, batch: Dict[str, jax.Array]) -> jax.Array:
@@ -495,8 +566,18 @@ class TransformerLM:
         ``jax.checkpoint`` on the chunk body keeps the backward pass at the
         same peak memory.
         """
-        cfg = self.cfg
         h, aux = self.hidden(params, batch)
+        return self._chunked_nll(params, h, batch) + aux
+
+    def loss_and_counts(self, params: PyTree, batch: Dict[str, jax.Array]):
+        """``loss``, and each decoder layer's per-expert count of routed
+        assignments (num_layers, E) (zero rows for layers without a
+        dropless MoE)."""
+        h, aux, counts = self.hidden(params, batch, with_counts=True)
+        return self._chunked_nll(params, h, batch) + aux, counts
+
+    def _chunked_nll(self, params: PyTree, h: jax.Array, batch: Dict[str, jax.Array]) -> jax.Array:
+        cfg = self.cfg
         if cfg.image_tokens and "image_emb" in batch:
             h = h[:, batch["image_emb"].shape[1] :]
         labels = batch["labels"].astype(jnp.int32)
@@ -517,7 +598,7 @@ class TransformerLM:
         def chunk_nll(carry, xs):
             hc, yc = xs
             hc = _pin_batch(hc, self.batch_axes)
-            logits = (hc @ w).astype(jnp.float32)                  # (B, C, V)
+            logits = dense(hc, w).astype(jnp.float32)              # (B, C, V)
             # keep the vocab axis model-sharded: logsumexp is then a sharded
             # reduction (psum under GSPMD), never an all-gather
             logits = _pin_spec(logits, self.batch_axes, (None, vocab_ax))
@@ -531,14 +612,14 @@ class TransformerLM:
             return carry + jnp.sum((logz - gold) * valid), None
 
         total, _ = jax.lax.scan(chunk_nll, jnp.zeros((), jnp.float32), (h_c, y_c))
-        nll = total / (b * s)
-        return nll + aux
+        return total / (b * s)
 
     # -- decode ---------------------------------------------------------------
     def init_cache(self, batch: int, cache_len: int) -> PyTree:
         cfg = self.cfg
         cache = _init_stack_cache(
-            cfg, batch, cache_len, self.dtype, pattern=cfg.pattern, num_layers=cfg.num_layers
+            cfg, batch, cache_len, self.dtype, pattern=cfg.pattern, num_layers=cfg.num_layers,
+            first_dense=cfg.first_dense_layers,
         )
         return cache
 

@@ -8,7 +8,8 @@ for a *described* ``v5e:2x2`` topology — no chip attached — and check that
 the kernel is really in the program (``tpu_custom_call``).
 
 D is the parameter count of ``PaperCNN(side=32, channels=3, num_classes=10,
-num_fc=3)``, P the paper's cohort size.  The topology is described inside a
+num_fc=3)``, P the paper's cohort size.  The MoE kernel compiles at
+DeepSeek-V2-Lite's expert widths for a 16,384-token cohort step.  The topology is described inside a
 module fixture (never at import), so only the worker that runs this file
 loads the TPU compiler.
 """
@@ -18,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
-from repro.kernels import aggregate, gram, topk_mask
+from repro.kernels import aggregate, gram, moe_gmm, topk_mask
 
 D = 595_914      # PaperCNN CIFAR-10 variant
 P = 10           # clients per round
@@ -107,4 +108,19 @@ def test_topk_mask_rows_compiles(one_chip, rows):
     """Fedcom's update transform (P rows) and the 1-D form (one row)."""
     _assert_kernel(topk_mask.topk_mask_rows.lower(
         _f32((rows, D), one_chip), keep_frac=0.1, interpret=False,
+    ))
+
+
+@pytest.mark.parametrize("k,n,transpose", [(2048, 1408, False), (1408, 2048, False),
+                                           (2048, 1408, True), (1408, 2048, True)])
+def test_moe_gmm_compiles_at_deepseek_widths(one_chip, k, n, transpose):
+    """DeepSeek-V2-Lite's grouped expert products: 16,384 tokens x top-6
+    rows over 64 experts; gate/up (2048 -> 1408) and down (1408 -> 2048),
+    and the backward pass's transposed dX products."""
+    m = moe_gmm.padded_rows(16_384 * 6)
+    rhs = (64, n, k) if transpose else (64, k, n)
+    bf16 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    _assert_kernel(moe_gmm.gmm.lower(
+        bf16((m, k)), bf16(rhs), jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip),
+        transpose_rhs=transpose, interpret=False,
     ))

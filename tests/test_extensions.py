@@ -66,12 +66,15 @@ def _moe_cfg():
 
 @pytest.mark.parametrize("group", [8, 16, 40])
 def test_moe_group_size_invariance_dropfree(group):
-    """Drop-free routing is per-token, so grouping must not change outputs."""
+    """Drop-free routing is per-token, so grouping must not change outputs:
+    the capacity path at a capacity of the whole group (E/k) against the
+    dropless path."""
     cfg = _moe_cfg()
     p = moe_mod.init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
     x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 20, 32)), jnp.float32)
-    ref, _ = moe_mod.apply_moe(p, x, cfg, capacity_factor=None, group_size=None)
-    got, _ = moe_mod.apply_moe(p, x, cfg, capacity_factor=None, group_size=group)
+    ref, _ = moe_mod.apply_moe(p, x, cfg, capacity_factor=None)
+    whole_group = cfg.moe.num_experts / cfg.moe.top_k
+    got, _ = moe_mod.apply_moe(p, x, cfg, capacity_factor=whole_group, group_size=group)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(got), rtol=2e-4, atol=1e-5)
 
 
