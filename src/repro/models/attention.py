@@ -5,7 +5,9 @@ Training/prefill attention is computed with an online-softmax scan over KV
 chunks, so peak memory is O(S * chunk) instead of O(S²) — mandatory for the
 prefill_32k shape, and the same decomposition the Pallas decode kernel uses
 (kernels/decode_attention.py validates the blocked algorithm bit-for-bit at
-small shapes).
+small shapes).  MLA's causal training attention runs a Pallas flash kernel
+instead (kernels/mla_attention.py), which visits only the blocks on or below
+the diagonal.
 """
 from __future__ import annotations
 
@@ -16,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.kernels.mla_attention import BLOCK as MLA_BLOCK, mla_attention
 from repro.models.layers import (
     apply_norm,
     apply_rope,
@@ -264,9 +267,6 @@ def attention_decode_step(
 # ---------------------------------------------------------------------------
 # multi-head latent attention (DeepSeek-V2)
 # ---------------------------------------------------------------------------
-MLA_KV_CHUNK = 512
-
-
 def init_mla(rng, cfg: ArchConfig, dtype) -> Dict:
     """MLA's projections, named as DeepSeek-V2's modeling code names them:
     ``q_proj`` (D, H·(nope+rope)), ``kv_a_proj`` (D, kv_lora + rope; the
@@ -328,18 +328,23 @@ def _mla_kv(params, c: jax.Array, k_pe: jax.Array, cfg: ArchConfig):
 
 
 def mla_block(params, x: jax.Array, positions: jax.Array, cfg: ArchConfig,
-              kv_chunk: int = MLA_KV_CHUNK) -> jax.Array:
+              kv_chunk: int = MLA_BLOCK) -> jax.Array:
     """Causal MLA over the whole sequence (training / prefill), without the
     norms and residual: ``q = x·W_q``; ``[c_kv, k_pe] = x·W_kva``;
     ``[k_nope, v] = RMSNorm(c_kv)·W_kvb``; YaRN RoPE on ``q_pe`` and the
-    shared ``k_pe``; chunked scores at ``mla_softmax_scale``; ``o·W_o``."""
+    shared ``k_pe``; causal flash attention at ``mla_softmax_scale``
+    (``kernels/mla_attention.py``, query and KV blocks of ``kv_chunk`` rows);
+    ``o·W_o``.
+
+    The kernel masks by each row's index, not by ``positions``: the two
+    agree because every caller (``TransformerLM.hidden``, for training and
+    eval) passes each row's ``arange``.  ``positions`` feed YaRN RoPE."""
     b, s = x.shape[:2]
     with jax.named_scope("mla/proj"):
         q, c, k_pe = _mla_qkv(params, x, positions, cfg)
         k, v = _mla_kv(params, c, k_pe, cfg)
     with jax.named_scope("mla/attend"):
-        out = chunked_attention(q, k, v, positions, positions, causal=True,
-                                kv_chunk=min(kv_chunk, s), scale=mla_softmax_scale(cfg))
+        out = mla_attention(q, k, v, scale=mla_softmax_scale(cfg), block=kv_chunk)
     with jax.named_scope("mla/proj"):
         return dense(out.reshape(b, s, -1), params["o_proj"])
 

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ArchConfig
-from repro.kernels import moe_gmm
+from repro.kernels import fold_vmap, moe_gmm
 from repro.kernels.ref import moe_gmm_ref
 from repro.models.layers import activation, apply_mlp, dense_init, init_mlp
 
@@ -249,38 +249,6 @@ def _experts_bwd_impl(x, ids, gates, w_gate, w_up, w_down, d_out, act):
     return dx.astype(x.dtype), d_gates.reshape(n, k).astype(gates.dtype)
 
 
-def _fold(axis_size, in_batched, *args):
-    """Fold a vmapped axis into the token axis: each batched (V, N, ...) arg
-    becomes (V·N, ...); an unbatched one is broadcast first."""
-    return [a.reshape(axis_size * a.shape[1], *a.shape[2:]) if bat
-            else jnp.broadcast_to(a, (axis_size, *a.shape)).reshape(-1, *a.shape[1:])
-            for a, bat in zip(args, in_batched)]
-
-
-def _unfold(axis_size, *outs):
-    return tuple(o.reshape(axis_size, -1, *o.shape[1:]) for o in outs)
-
-
-def _token_vmap(impl, n_tokens: int):
-    """``impl`` (its first ``n_tokens`` args token-major, the rest the
-    expert weights) as a ``custom_vmap``: a mapped call folds the mapped
-    axis into the tokens and runs as one call."""
-    fn = jax.custom_batching.custom_vmap(impl)
-
-    @fn.def_vmap
-    def rule(axis_size, in_batched, *args):
-        if any(in_batched[n_tokens:]):
-            raise NotImplementedError("per-client expert weights under vmap")
-        folded = _fold(axis_size, in_batched[:n_tokens], *args[:n_tokens])
-        out = fn(*folded, *args[n_tokens:])
-        outs = out if isinstance(out, tuple) else (out,)
-        unfolded = _unfold(axis_size, *outs)
-        batched = tuple(True for _ in outs)
-        return (unfolded, batched) if isinstance(out, tuple) else (unfolded[0], True)
-
-    return fn
-
-
 def _weight_grads(act: str):
     """The expert weights' cotangents ``(x, ids, gates, d_out, w_gate, w_up,
     w_down) -> (dW_gate, dW_up, dW_down)``, through ``lax.ragged_dot``'s
@@ -312,8 +280,8 @@ def routed_experts(x, ids, gates, w_gate, w_up, w_down, act: str = "silu"):
     backward pass computes the expert weights' cotangent only where they
     are differentiated (``symbolic_zeros``): frozen experts cost none."""
     if act not in _EXPERTS:
-        fwd = _token_vmap(lambda x, i, g, wg, wu, wd: _experts_fwd_impl(x, i, g, wg, wu, wd, act), 3)
-        bwd = _token_vmap(
+        fwd = fold_vmap(lambda x, i, g, wg, wu, wd: _experts_fwd_impl(x, i, g, wg, wu, wd, act), 3)
+        bwd = fold_vmap(
             lambda x, i, g, d, wg, wu, wd: _experts_bwd_impl(x, i, g, wg, wu, wd, d, act), 4)
         weight_grads = _weight_grads(act)
 

@@ -9,17 +9,20 @@ the kernel is really in the program (``tpu_custom_call``).
 
 D is the parameter count of ``PaperCNN(side=32, channels=3, num_classes=10,
 num_fc=3)``, P the paper's cohort size.  The MoE kernel compiles at
-DeepSeek-V2-Lite's expert widths for a 16,384-token cohort step.  The topology is described inside a
+DeepSeek-V2-Lite's expert widths for a 16,384-token cohort step, and MLA's
+causal attention at its head sizes for the same step.  The topology is described inside a
 module fixture (never at import), so only the worker that runs this file
 loads the TPU compiler.
 """
+import re
+
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
-from repro.kernels import aggregate, gram, moe_gmm, topk_mask
+from repro.kernels import aggregate, gram, mla_attention, moe_gmm, topk_mask
 
 D = 595_914      # PaperCNN CIFAR-10 variant
 P = 10           # clients per round
@@ -124,3 +127,25 @@ def test_moe_gmm_compiles_at_deepseek_widths(one_chip, k, n, transpose):
         bf16((m, k)), bf16(rhs), jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip),
         transpose_rhs=transpose, interpret=False,
     ))
+
+
+def test_mla_attention_compiles_at_deepseek_widths(one_chip):
+    """DeepSeek-V2-Lite's causal attention in a cohort step: 4 clients x 2 x
+    2,048 tokens, 16 heads, qk 192, v 128, forward and backward.  The
+    forward, dQ and dK/dV kernels are in the program, and no (S, S) or
+    (S, H, C) score buffer is (C = 512, the blocks the parent scanned)."""
+    s, c = 2048, 512
+    q = jax.ShapeDtypeStruct((4, 2, s, 16, 192), jnp.float32, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((4, 2, s, 16, 128), jnp.float32, sharding=one_chip)
+
+    def loss(q, k, v):
+        f = lambda q, k, v: mla_attention.mla_attention(q, k, v, scale=0.11472,
+                                                        interpret=False)
+        return jnp.sum(jax.vmap(f)(q, k, v))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(q, q, v).compile().as_text()
+    assert "tpu_custom_call" in text
+    for kernel in ("mla_attention_fwd", "mla_attention_dq", "mla_attention_dkv"):
+        assert f"%{kernel}." in text
+    dims = [tuple(int(d) for d in m.split(",")) for m in re.findall(r"\[([\d,]+)\]", text)]
+    assert not [d for d in dims if d.count(s) >= 2 or (s in d and c in d)]
